@@ -18,5 +18,5 @@ if __name__ == "__main__":
     # 16 experts -- the structure (GQA + qk_norm + fine-grained MoE top-8)
     # is preserved.
     train("qwen3-235b-a22b", steps=args.steps, batch=8, seq=256,
-          d_model=512, layers=4, balancer=args.balancer,
+          reduce=True, d_model=512, num_layers=4, balancer=args.balancer,
           microbatches=2, ckpt_dir="/tmp/repro_100m_ckpt")

@@ -1,4 +1,4 @@
-"""Family-faithful reduced configs: same structure, tiny dimensions.
+"""Cuts of a config: ``reduced`` for CPU tests, ``depth_cut`` for the chip.
 
 ``reduced(cfg)`` keeps everything that defines the architecture family --
 attention flavour (GQA/MLA, bias, qk_norm), MoE layout (expert count ratio,
@@ -7,6 +7,10 @@ periods, frontend stubs, tying -- while shrinking widths/depths so a
 forward/train step runs in milliseconds on CPU.  Used by the per-arch smoke
 tests (brief: "a REDUCED config of the same family") and the train/serve
 example drivers.
+
+``depth_cut(cfg, n)`` keeps every width as published and cuts only the
+depth, to whole structural periods: what a chip run at published widths
+uses when all layers do not fit.
 """
 
 from __future__ import annotations
@@ -15,18 +19,41 @@ import dataclasses
 
 from repro.configs.base import ModelConfig, MoEArch, SSMArch
 
-__all__ = ["reduced"]
+__all__ = ["depth_cut", "reduced"]
 
 
-def reduced(cfg: ModelConfig, *, layers: int | None = None,
-            d_model: int = 64, vocab: int = 512) -> ModelConfig:
-    # Depth: keep >= one full structural period.
+def _prefix_period(cfg: ModelConfig) -> tuple[int, int]:
+    """(dense prefix layers, layers per structural period)."""
     period = 1
     if cfg.ssm is not None and cfg.ssm.attn_period:
         period = max(period, cfg.ssm.attn_period)
     if cfg.moe is not None:
         period = max(period, cfg.moe.layer_period)
     prefix = cfg.moe.first_dense_layers if cfg.moe else 0
+    return prefix, period
+
+
+def depth_cut(cfg: ModelConfig, num_layers: int | None) -> ModelConfig:
+    """``cfg`` at its published widths with only ``num_layers`` layers.
+
+    The cut keeps the dense prefix and a whole number of periods, so every
+    layer kind of the full model is still present.  None keeps the depth.
+    """
+    if num_layers is None:
+        return cfg
+    prefix, period = _prefix_period(cfg)
+    if num_layers < prefix + period or (num_layers - prefix) % period:
+        raise ValueError(
+            f"{cfg.name}: num_layers={num_layers} must be the {prefix} dense "
+            f"prefix layers plus a whole number (>= 1) of {period}-layer "
+            "periods")
+    return dataclasses.replace(cfg, num_layers=num_layers)
+
+
+def reduced(cfg: ModelConfig, *, layers: int | None = None,
+            d_model: int = 64, vocab: int = 512) -> ModelConfig:
+    # Depth: keep >= one full structural period.
+    prefix, period = _prefix_period(cfg)
     L = layers if layers is not None else max(prefix + period, 2)
 
     moe = None

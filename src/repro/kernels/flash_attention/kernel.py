@@ -68,10 +68,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("causal", "bq", "bk", "interpret"))
+                   static_argnames=("causal", "bq", "bk"))
 def flash_fwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                     causal: bool = True, bq: int = 256, bk: int = 512,
-                     interpret: bool = False) -> jax.Array:
+                     causal: bool = True, bq: int = 256,
+                     bk: int = 512) -> jax.Array:
     """q/k/v: (BH, S, d) with heads pre-flattened into the batch dim."""
     BH, Sq, d = q.shape
     Sk = k.shape[1]
@@ -97,5 +97,4 @@ def flash_fwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
     )(q, k, v)

@@ -23,7 +23,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, Sk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, d)
-    interpret = jax.default_backend() != "tpu"
     out = flash_fwd_pallas(qf, kf, vf, causal=causal, bq=min(bq, Sq),
-                           bk=min(bk, Sk), interpret=interpret)
+                           bk=min(bk, Sk))
     return out.reshape(B, H, Sq, d).transpose(0, 2, 1, 3)

@@ -46,10 +46,9 @@ def _kernel(logit_ref, ids_ref, w_ref, cnt_ref, *, k: int, score_fn: str,
     cnt_ref[...] = cnt[None, :]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "score_fn", "bt",
-                                              "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "score_fn", "bt"))
 def gating_topk_pallas(logits: jax.Array, k: int, *, score_fn: str = "softmax",
-                       bt: int = 1024, interpret: bool = False):
+                       bt: int = 1024):
     """logits: (T, E).  Returns (ids, weights, counts)."""
     T, E = logits.shape
     bt = min(bt, T)
@@ -70,6 +69,5 @@ def gating_topk_pallas(logits: jax.Array, k: int, *, score_fn: str = "softmax",
             jax.ShapeDtypeStruct((T, k), jnp.float32),
             jax.ShapeDtypeStruct((T // bt, E), jnp.int32),
         ],
-        interpret=interpret,
     )(logits)
     return ids, w, cnt.sum(axis=0)

@@ -82,10 +82,11 @@ def grouped_matmul_q8_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
                              k_steps: int):
     """w8a8 tile: int8 x int8 -> int32 MXU accumulation, dequant at the end.
 
-    The per-row activation scales (bm,) and per-column weight scales (bn,)
-    dequantize the int32 accumulator as a rank-1 outer product on the final
-    K step -- scales never enter the contraction, so the integer arithmetic
-    is exact and the only rounding is the one the encoder already paid.
+    The per-row activation scales (bm, 1) and per-column weight scales
+    (1, bn) dequantize the int32 accumulator as a rank-1 outer product on
+    the final K step -- scales never enter the contraction, so the integer
+    arithmetic is exact and the only rounding is the one the encoder
+    already paid.
     """
     @pl.when(pl.program_id(3) == 0)
     def _init():
@@ -100,7 +101,7 @@ def grouped_matmul_q8_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
     @pl.when(pl.program_id(3) == k_steps - 1)
     def _store():
         o_ref[0, ...] = (acc_ref[...].astype(jnp.float32)
-                         * xs_ref[0][:, None] * ws_ref[0][None, :])
+                         * xs_ref[0] * ws_ref[0])
 
 
 def grouped_swiglu_q8_kernel(x_ref, w1_ref, w3_ref, xs_ref, w1s_ref, w3s_ref,
@@ -131,17 +132,17 @@ def grouped_swiglu_q8_kernel(x_ref, w1_ref, w3_ref, xs_ref, w1s_ref, w3s_ref,
 
     @pl.when(pl.program_id(3) == k_steps - 1)
     def _store():
-        rs = xs_ref[0][:, None]
-        h = acc_h[...].astype(jnp.float32) * rs * w1s_ref[0][None, :]
-        g = acc_g[...].astype(jnp.float32) * rs * w3s_ref[0][None, :]
+        rs = xs_ref[0]
+        h = acc_h[...].astype(jnp.float32) * rs * w1s_ref[0]
+        g = acc_g[...].astype(jnp.float32) * rs * w3s_ref[0]
         o_ref[0, ...] = h * jax.lax.logistic(h) * g
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "interpret"))
+                   static_argnames=("bm", "bn", "bk"))
 def grouped_swiglu_pallas(x: jax.Array, w1: jax.Array, w3: jax.Array, *,
-                          bm: int = 128, bn: int = 128, bk: int = 128,
-                          interpret: bool = False) -> jax.Array:
+                          bm: int = 128, bn: int = 128,
+                          bk: int = 128) -> jax.Array:
     """x: (G, M, K), w1/w3: (G, K, N) -> silu(x@w1) * (x@w3): (G, M, N)."""
     G, M, K = x.shape
     _, _, N = w1.shape
@@ -163,15 +164,13 @@ def grouped_swiglu_pallas(x: jax.Array, w1: jax.Array, w3: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((G, M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
     )(x, w1, w3)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "interpret"))
+                   static_argnames=("bm", "bn", "bk"))
 def grouped_matmul_pallas(x: jax.Array, w: jax.Array, *, bm: int = 128,
-                          bn: int = 128, bk: int = 128,
-                          interpret: bool = False) -> jax.Array:
+                          bn: int = 128, bk: int = 128) -> jax.Array:
     """x: (G, M, K) @ w: (G, K, N) -> (G, M, N)."""
     G, M, K = x.shape
     _, _, N = w.shape
@@ -191,16 +190,15 @@ def grouped_matmul_pallas(x: jax.Array, w: jax.Array, *, bm: int = 128,
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
     )(x, w)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "interpret"))
+                   static_argnames=("bm", "bn", "bk"))
 def grouped_matmul_q8_pallas(q: jax.Array, row_scale: jax.Array,
                              wq: jax.Array, col_scale: jax.Array, *,
-                             bm: int = 128, bn: int = 128, bk: int = 128,
-                             interpret: bool = False) -> jax.Array:
+                             bm: int = 128, bn: int = 128,
+                             bk: int = 128) -> jax.Array:
     """q: (G, M, K) int8, row_scale: (G, M); wq: (G, K, N) int8,
     col_scale: (G, N) -> dequantized (G, M, N) fp32."""
     G, M, K = q.shape
@@ -217,23 +215,22 @@ def grouped_matmul_q8_pallas(q: jax.Array, row_scale: jax.Array,
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda g, i, j, k: (g, i, k)),
             pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g, k, j)),
-            pl.BlockSpec((1, bm), lambda g, i, j, k: (g, i)),
-            pl.BlockSpec((1, bn), lambda g, i, j, k: (g, j)),
+            pl.BlockSpec((1, bm, 1), lambda g, i, j, k: (g, i, 0)),
+            pl.BlockSpec((1, 1, bn), lambda g, i, j, k: (g, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
-    )(q, wq, row_scale, col_scale)
+    )(q, wq, row_scale[:, :, None], col_scale[:, None, :])
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "interpret"))
+                   static_argnames=("bm", "bn", "bk"))
 def grouped_swiglu_q8_pallas(q: jax.Array, row_scale: jax.Array,
                              w1q: jax.Array, w1s: jax.Array,
                              w3q: jax.Array, w3s: jax.Array, *,
-                             bm: int = 128, bn: int = 128, bk: int = 128,
-                             interpret: bool = False) -> jax.Array:
+                             bm: int = 128, bn: int = 128,
+                             bk: int = 128) -> jax.Array:
     """w8a8 fused ``silu(x@w1) * (x@w3)``; scales as in the matmul variant."""
     G, M, K = q.shape
     _, _, N = w1q.shape
@@ -250,13 +247,12 @@ def grouped_swiglu_q8_pallas(q: jax.Array, row_scale: jax.Array,
             pl.BlockSpec((1, bm, bk), lambda g, i, j, k: (g, i, k)),
             pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g, k, j)),
             pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g, k, j)),
-            pl.BlockSpec((1, bm), lambda g, i, j, k: (g, i)),
-            pl.BlockSpec((1, bn), lambda g, i, j, k: (g, j)),
-            pl.BlockSpec((1, bn), lambda g, i, j, k: (g, j)),
+            pl.BlockSpec((1, bm, 1), lambda g, i, j, k: (g, i, 0)),
+            pl.BlockSpec((1, 1, bn), lambda g, i, j, k: (g, 0, j)),
+            pl.BlockSpec((1, 1, bn), lambda g, i, j, k: (g, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32),
                         pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
-    )(q, w1q, w3q, row_scale, w1s, w3s)
+    )(q, w1q, w3q, row_scale[:, :, None], w1s[:, None, :], w3s[:, None, :])

@@ -1,4 +1,4 @@
-"""Public grouped-matmul op: Pallas on TPU, interpret mode elsewhere."""
+"""Public grouped-matmul ops: pad to block multiples, call the Pallas kernel."""
 
 from __future__ import annotations
 
@@ -30,22 +30,18 @@ def grouped_matmul(x: jax.Array, w: jax.Array, *, bm: int = 128,
                    bn: int = 128, bk: int = 128) -> jax.Array:
     """Grouped matmul with automatic padding to block multiples.
 
-    Uses the Pallas kernel on TPU backends, interpret mode on CPU (same
-    kernel body, Python evaluation).  Falls back to the jnp oracle for
-    shapes too small to tile profitably.
+    Falls back to the jnp oracle for shapes too small to tile profitably.
     """
     G, M, K = x.shape
     _, _, N = w.shape
     if M * N * K < 128 ** 3:  # tiny: tiling overhead dominates
         return grouped_matmul_ref(x, w)
-    interpret = jax.default_backend() != "tpu"
     bm2, bn2, bk2 = min(bm, _pad_to(M, 8)), min(bn, _pad_to(N, 128)), \
         min(bk, _pad_to(K, 128))
     Mp, Np, Kp = _pad_to(M, bm2), _pad_to(N, bn2), _pad_to(K, bk2)
     xp = jnp.pad(x, ((0, 0), (0, Mp - M), (0, Kp - K)))
     wp = jnp.pad(w, ((0, 0), (0, Kp - K), (0, Np - N)))
-    out = grouped_matmul_pallas(xp, wp, bm=bm2, bn=bn2, bk=bk2,
-                                interpret=interpret)
+    out = grouped_matmul_pallas(xp, wp, bm=bm2, bn=bn2, bk=bk2)
     return out[:, :M, :N]
 
 
@@ -55,23 +51,21 @@ def grouped_swiglu(x: jax.Array, w1: jax.Array, w3: jax.Array, *,
 
     One kernel invocation reads each x block once for both contractions and
     keeps the h/g intermediates in VMEM (vs two grouped GEMMs + an
-    elementwise pass that round-trips them through HBM).  Pallas on TPU
-    backends, interpret mode on CPU; jnp oracle for sub-tile shapes.
+    elementwise pass that round-trips them through HBM).  jnp oracle for
+    sub-tile shapes.
     Zero-padding is safe: silu(0) * 0 == 0 on the padded rows/cols.
     """
     G, M, K = x.shape
     _, _, N = w1.shape
     if M * N * K < 128 ** 3:  # tiny: tiling overhead dominates
         return grouped_swiglu_ref(x, w1, w3)
-    interpret = jax.default_backend() != "tpu"
     bm2, bn2, bk2 = min(bm, _pad_to(M, 8)), min(bn, _pad_to(N, 128)), \
         min(bk, _pad_to(K, 128))
     Mp, Np, Kp = _pad_to(M, bm2), _pad_to(N, bn2), _pad_to(K, bk2)
     xp = jnp.pad(x, ((0, 0), (0, Mp - M), (0, Kp - K)))
     w1p = jnp.pad(w1, ((0, 0), (0, Kp - K), (0, Np - N)))
     w3p = jnp.pad(w3, ((0, 0), (0, Kp - K), (0, Np - N)))
-    out = grouped_swiglu_pallas(xp, w1p, w3p, bm=bm2, bn=bn2, bk=bk2,
-                                interpret=interpret)
+    out = grouped_swiglu_pallas(xp, w1p, w3p, bm=bm2, bn=bn2, bk=bk2)
     return out[:, :M, :N]
 
 
@@ -88,7 +82,6 @@ def grouped_matmul_q8(q: jax.Array, row_scale: jax.Array, wq: jax.Array,
     _, _, N = wq.shape
     if M * N * K < 128 ** 3:  # tiny: tiling overhead dominates
         return grouped_matmul_q8_ref(q, row_scale, wq, col_scale)
-    interpret = jax.default_backend() != "tpu"
     bm2, bn2, bk2 = min(bm, _pad_to(M, 32)), min(bn, _pad_to(N, 128)), \
         min(bk, _pad_to(K, 128))
     Mp, Np, Kp = _pad_to(M, bm2), _pad_to(N, bn2), _pad_to(K, bk2)
@@ -96,8 +89,7 @@ def grouped_matmul_q8(q: jax.Array, row_scale: jax.Array, wq: jax.Array,
     wp = jnp.pad(wq, ((0, 0), (0, Kp - K), (0, Np - N)))
     rs = jnp.pad(row_scale, ((0, 0), (0, Mp - M)))
     cs = jnp.pad(col_scale, ((0, 0), (0, Np - N)))
-    out = grouped_matmul_q8_pallas(qp, rs, wp, cs, bm=bm2, bn=bn2, bk=bk2,
-                                   interpret=interpret)
+    out = grouped_matmul_q8_pallas(qp, rs, wp, cs, bm=bm2, bn=bn2, bk=bk2)
     return out[:, :M, :N]
 
 
@@ -114,7 +106,6 @@ def grouped_swiglu_q8(q: jax.Array, row_scale: jax.Array,
     _, _, N = w1q.shape
     if M * N * K < 128 ** 3:  # tiny: tiling overhead dominates
         return grouped_swiglu_q8_ref(q, row_scale, w1q, w1s, w3q, w3s)
-    interpret = jax.default_backend() != "tpu"
     bm2, bn2, bk2 = min(bm, _pad_to(M, 32)), min(bn, _pad_to(N, 128)), \
         min(bk, _pad_to(K, 128))
     Mp, Np, Kp = _pad_to(M, bm2), _pad_to(N, bn2), _pad_to(K, bk2)
@@ -125,5 +116,5 @@ def grouped_swiglu_q8(q: jax.Array, row_scale: jax.Array,
     s1 = jnp.pad(w1s, ((0, 0), (0, Np - N)))
     s3 = jnp.pad(w3s, ((0, 0), (0, Np - N)))
     out = grouped_swiglu_q8_pallas(qp, rs, w1p, s1, w3p, s3, bm=bm2, bn=bn2,
-                                   bk=bk2, interpret=interpret)
+                                   bk=bk2)
     return out[:, :M, :N]

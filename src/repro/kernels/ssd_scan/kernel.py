@@ -54,8 +54,8 @@ def _kernel(x_ref, b_ref, c_ref, dt_ref, da_ref, y_ref, s_ref, dec_ref, *,
     dec_ref[0, 0, 0] = jnp.exp(last).astype(dec_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_intra_chunk_pallas(xs, Bm, Cm, dt, da, *, interpret: bool = False):
+@jax.jit
+def ssd_intra_chunk_pallas(xs, Bm, Cm, dt, da):
     """xs: (B, nc, Q, H, P); Bm/Cm: (B, nc, Q, H, N); dt/da: (B, nc, Q, H).
 
     Returns (y_intra (B,nc,Q,H,P), S_chunk (B,nc,H,N,P), decay (B,nc,H)).
@@ -83,6 +83,5 @@ def ssd_intra_chunk_pallas(xs, Bm, Cm, dt, da, *, interpret: bool = False):
             jax.ShapeDtypeStruct((B, nc, H, N, P), jnp.float32),
             jax.ShapeDtypeStruct((B, nc, H), jnp.float32),
         ],
-        interpret=interpret,
     )(xs, Bm, Cm, dt, da)
     return y, S, dec

@@ -17,9 +17,7 @@ def ssd_chunk_scan(xs, Bm, Cm, dt, da, *, initial_state=None):
     """
     B, nc, Q, H, P = xs.shape
     N = Bm.shape[-1]
-    interpret = jax.default_backend() != "tpu"
-    y_intra, S_c, chunk_decay = ssd_intra_chunk_pallas(
-        xs, Bm, Cm, dt, da, interpret=interpret)
+    y_intra, S_c, chunk_decay = ssd_intra_chunk_pallas(xs, Bm, Cm, dt, da)
 
     def scan_fn(s_prev, blk):
         s_new = s_prev * blk["decay"][:, :, None, None] + blk["S"]

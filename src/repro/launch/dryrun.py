@@ -55,16 +55,15 @@ def _period(cfg):
 
 
 def _lower_compile(cell, mesh):
-    if hasattr(jax, "set_mesh"):      # newer jax; explicit meshes work without
-        jax.set_mesh(mesh)
-    t0 = time.time()
-    jitted = jax.jit(cell.step_fn, in_shardings=cell.in_shardings,
-                     donate_argnums=cell.donate)
-    lowered = jitted.lower(*cell.arg_shapes)
-    t_lower = time.time() - t0
-    t0 = time.time()
-    compiled = lowered.compile()
-    t_compile = time.time() - t0
+    with jax.set_mesh(mesh):          # in_shardings are bare PartitionSpecs
+        t0 = time.time()
+        jitted = jax.jit(cell.step_fn, in_shardings=cell.in_shardings,
+                         donate_argnums=cell.donate)
+        lowered = jitted.lower(*cell.arg_shapes)
+        t_lower = time.time() - t0
+        t0 = time.time()
+        compiled = lowered.compile()
+        t_compile = time.time() - t0
     return lowered, compiled, t_lower, t_compile
 
 

@@ -62,7 +62,8 @@ def make_rack_mesh(data: int = 1, racks: int = 2, lanes: int = 4):
 
 
 def make_test_mesh(data: int = 2, model: int = 4):
-    """Small mesh for subprocess CPU tests (8 virtual devices)."""
+    """(data, model) mesh over the first ``data * model`` devices: virtual
+    CPU devices in tests, or the chips of one host."""
     return _mesh((data, model), ("data", "model"))
 
 

@@ -29,14 +29,14 @@ from repro.optim import adafactor, adamw
 from repro.parallel import sharding as shard_rules
 from repro.train.loop import TrainConfig, TrainState, init_train_state, make_train_step
 
-__all__ = ["Cell", "build_cell", "shape_supported", "supported_shapes",
-           "runtime_for"]
+__all__ = ["BIG_ARCHS", "Cell", "build_cell", "shape_supported",
+           "supported_shapes", "runtime_for", "train_state_specs"]
 
 # Archs whose AdamW state cannot fit the single-pod HBM budget use Adafactor
-# for the dry-run (documented in DESIGN.md S7 / EXPERIMENTS.md).
-_BIG = {"qwen2-72b", "mistral-large-123b", "deepseek-v3-671b", "dbrx-132b",
-        "qwen3-235b-a22b", "glm45-106b-a12b", "jamba-v0.1-52b",
-        "internvl2-26b"}
+# for the dry-run and for training at published widths (DESIGN.md S7).
+BIG_ARCHS = {"qwen2-72b", "mistral-large-123b", "deepseek-v3-671b",
+             "dbrx-132b", "qwen3-235b-a22b", "glm45-106b-a12b",
+             "jamba-v0.1-52b", "internvl2-26b"}
 
 
 class Cell(NamedTuple):
@@ -80,6 +80,19 @@ def runtime_for(cfg: ModelConfig, shape: ShapeSpec, *, balancer_mode="ultraep",
     )
     kw.update(overrides)
     return RuntimeConfig(**kw)
+
+
+def train_state_specs(param_specs, state_shape: TrainState) -> TrainState:
+    """PartitionSpecs of a :class:`TrainState` whose params have
+    ``param_specs``; ``state_shape`` gives the optimizer-state structure."""
+    return TrainState(
+        params=param_specs,
+        opt_state=shard_rules.opt_state_specs(param_specs,
+                                              state_shape.opt_state),
+        router_bias=(None if state_shape.router_bias is None
+                     else P(None, None)),
+        step=P(),
+    )
 
 
 def _batch_shapes(cfg: ModelConfig, shape: ShapeSpec, kind: str):
@@ -130,17 +143,10 @@ def build_cell(
     meta = {"cfg": cfg, "rcfg": rcfg, "shape": shape}
 
     if shape.kind == "train":
-        opt = (adafactor(1e-4) if arch in _BIG else adamw(3e-4))
+        opt = (adafactor(1e-4) if arch in BIG_ARCHS else adamw(3e-4))
         state_shape = jax.eval_shape(
             lambda: init_train_state(params_shape, opt, cfg))
-        sspecs = TrainState(
-            params=pspecs,
-            opt_state=shard_rules.opt_state_specs(pspecs,
-                                                  state_shape.opt_state),
-            router_bias=(None if state_shape.router_bias is None
-                         else P(None, None)),
-            step=P(),
-        )
+        sspecs = train_state_specs(pspecs, state_shape)
         step = make_train_step(cfg, rcfg, pctx, opt,
                                TrainConfig(microbatches=microbatches))
         return Cell(arch, shape_name, step, (state_shape, bshapes),

@@ -43,23 +43,7 @@ from repro.moe.layer import (
 __all__ = ["RuntimeConfig", "ParallelCtx", "BlockParams", "Segment",
            "build_segments", "segments_for", "segment_apply", "attn_config",
            "ssm_config", "moe_config", "effective_rack_limit", "init_block",
-           "init_cache_block", "shard_map_compat"]
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions (check_vma vs legacy check_rep).
-
-    TypeError covers the promotion window where ``jax.shard_map`` exists
-    but still takes ``check_rep``.
-    """
-    try:
-        from jax import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+           "init_cache_block"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -439,8 +423,8 @@ def _ep_moe_block(x: jax.Array, mp: MoEParams, mcfg: MoEConfig,
     has_shared = mp.shared_w1 is not None
     sw_spec = P(None, None) if has_shared else P()
     bias_spec = P(None) if router_bias is not None else P()
-    fn = shard_map_compat(
-        local, mesh=pctx.mesh,
+    fn = jax.shard_map(
+        local, mesh=pctx.mesh, check_vma=False,
         in_specs=(x_spec, P(None, None), P(ma, None, None),
                   P(ma, None, None), P(ma, None, None), sw_spec, sw_spec,
                   sw_spec, bias_spec),

@@ -14,22 +14,28 @@ __all__ = ["make_engine_fns"]
 
 def make_engine_fns(params: LMParams, cfg: ModelConfig, rcfg: RuntimeConfig,
                     pctx: ParallelCtx, *, max_seq: int):
-    """Returns (prefill_fn, decode_fn, new_cache_fn, stack_caches)."""
+    """Returns (prefill_fn, decode_fn, new_cache_fn, stack_caches,
+    unstack_caches).
+
+    ``params`` enter the jitted steps as arguments: closed over, they would
+    be baked into each program as constants.
+    """
 
     @jax.jit
-    def _prefill(tokens, caches, valid_len):
+    def _prefill(params, tokens, caches, valid_len):
         return prefill_step(params, caches, tokens, cfg, rcfg, pctx,
                             valid_len=valid_len)
 
     @jax.jit
-    def _decode(tokens, caches):
+    def _decode(params, tokens, caches):
         return decode_step(params, caches, tokens, cfg, rcfg, pctx)
 
     def prefill_fn(tokens, caches, start, valid_len):
-        return _prefill(tokens, caches, jnp.asarray(valid_len, jnp.int32))
+        return _prefill(params, tokens, caches,
+                        jnp.asarray(valid_len, jnp.int32))
 
     def decode_fn(tokens, caches):
-        return _decode(tokens, caches)
+        return _decode(params, tokens, caches)
 
     def new_cache_fn(batch):
         return init_caches(cfg, batch, max_seq, rcfg)

@@ -18,6 +18,7 @@ import dataclasses
 from collections import deque
 from typing import Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -116,7 +117,13 @@ class ServingEngine:
             row = np.where(finite, row, -np.inf)
         return int(np.argmax(row))
 
-    def _prefill(self, req: Request) -> tuple[object, object]:
+    def prefill(self, req: Request) -> tuple[object, object]:
+        """Prefill ``req.prompt`` chunk by chunk.
+
+        Returns (logits (V,) of the last prompt token, cache).  The last
+        chunk is right-padded, so its last real token sits at ``length - 1``
+        and not at the end of the chunk.
+        """
         cache = self.new_cache_fn(1)
         last_logits = None
         # Same chunking helper as the MoE overlap driver
@@ -126,19 +133,21 @@ class ServingEngine:
             chunk = req.prompt[pos: pos + length]
             pad = self.cfg.chunk_size - length
             toks = np.pad(chunk, (0, pad))[None, :]
-            last_logits, cache = self.prefill_fn(
+            logits, cache = self.prefill_fn(
                 jnp.asarray(toks, jnp.int32), cache, pos, length)
+            last_logits = logits[0, length - 1]
             self._advance(self.clock_fn() if self.clock_fn else 0.0)
         return last_logits, cache
 
     def run(self, until_empty: bool = True):
         """Alternate prefill and decode until queues drain.
 
-        Model-call failures (``RuntimeError``: injected planner/transfer
-        faults and their real counterparts) never escape: the call is
-        retried up to ``cfg.max_retries`` times, after which the affected
-        request (prefill) or decode group is retired as failed and the
-        queue keeps draining.
+        Transient model-call faults (``RuntimeError``: the injected
+        planner/transfer faults) never escape: the call is retried up to
+        ``cfg.max_retries`` times, after which the affected request
+        (prefill) or decode group is retired as failed and the queue keeps
+        draining.  A device error (``jax.errors.JaxRuntimeError``: out of
+        memory, a failed or lost device) is not transient and escapes.
         """
         while self.waiting or self.decoding:
             # 1. Prefill the oldest waiting request, chunk by chunk.
@@ -149,8 +158,10 @@ class ServingEngine:
                 last_logits = cache = None
                 for attempt in range(self.cfg.max_retries + 1):
                     try:
-                        last_logits, cache = self._prefill(req)
+                        last_logits, cache = self.prefill(req)
                         break
+                    except jax.errors.JaxRuntimeError:
+                        raise
                     except RuntimeError:
                         # Retry the whole prefill; the chunk loop mutates
                         # only local state so a clean restart is safe.
@@ -162,7 +173,7 @@ class ServingEngine:
                     req.first_token_at = self.now
                     # Host-side scheduling layer (module docstring): reading
                     # results back is the point, never under jit.
-                    first = self._argmax_token(np.asarray(last_logits)[0, -1])  # uep-lint: disable=host-sync
+                    first = self._argmax_token(np.asarray(last_logits))  # uep-lint: disable=host-sync
                     req.output = [first]
                     self.decoding.append((req, cache))
 
@@ -170,14 +181,21 @@ class ServingEngine:
             if self.decoding and (len(self.decoding) >= self.cfg.decode_batch
                                   or not self.waiting):
                 group = self.decoding[: self.cfg.decode_batch]
-                toks = np.array([[r.output[-1]] for r, _ in group], np.int32)  # uep-lint: disable=host-sync
-                caches = self.stack_caches([c for _, c in group])
+                # A short group is padded with copies of its first row, so
+                # decode always runs at one shape and compiles once.
+                pad = self.cfg.decode_batch - len(group)
+                toks = np.array([[r.output[-1]] for r, _ in group]  # uep-lint: disable=host-sync
+                                + [[group[0][0].output[-1]]] * pad, np.int32)
+                caches = self.stack_caches([c for _, c in group]
+                                           + [group[0][1]] * pad)
                 logits = None
                 for attempt in range(self.cfg.max_retries + 1):
                     try:
                         logits, caches = self.decode_fn(jnp.asarray(toks),
                                                         caches)
                         break
+                    except jax.errors.JaxRuntimeError:
+                        raise
                     except RuntimeError:
                         if attempt == self.cfg.max_retries:
                             # Retire the whole group: a decode step that
@@ -210,8 +228,6 @@ class ServingEngine:
 
     @staticmethod
     def unstack(caches, n):
-        import jax
-
         return [jax.tree.map(lambda a, i=i: a[i:i + 1]
                              if hasattr(a, "ndim") and a.ndim > 0 else a,
                              caches) for i in range(n)]

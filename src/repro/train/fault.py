@@ -29,7 +29,7 @@ __all__ = ["SupervisorConfig", "Supervisor"]
 
 @dataclasses.dataclass
 class SupervisorConfig:
-    checkpoint_dir: str
+    checkpoint_dir: str | None      # None: no checkpoints, so no recovery
     checkpoint_every: int = 50
     max_restarts: int = 3
     straggler_zscore: float = 3.0
@@ -49,7 +49,8 @@ class Supervisor:
         self.batch_fn = batch_fn
         self.rebuild_fn = rebuild_fn
         self.state_shardings = state_shardings
-        self.ckpt = Checkpointer(cfg.checkpoint_dir)
+        self.ckpt = (Checkpointer(cfg.checkpoint_dir)
+                     if cfg.checkpoint_dir is not None else None)
         self.restarts = 0
         self.step_times: list[float] = []
         self._ewma = None
@@ -107,7 +108,8 @@ class Supervisor:
                 step += 1
                 if on_metrics is not None:
                     on_metrics(step, metrics)
-                if step % self.cfg.checkpoint_every == 0:
+                if (self.ckpt is not None
+                        and step % self.cfg.checkpoint_every == 0):
                     self.ckpt.save(step, state)
             except (jax.errors.JaxRuntimeError, RuntimeError) as e:
                 self.restarts += 1
@@ -115,7 +117,8 @@ class Supervisor:
                     raise RuntimeError(
                         f"supervisor: giving up after {self.restarts} restarts"
                     ) from e
-                latest = self.ckpt.latest_step()
+                latest = (self.ckpt.latest_step()
+                          if self.ckpt is not None else None)
                 if latest is None:
                     raise
                 if self.rebuild_fn is not None:
@@ -124,5 +127,6 @@ class Supervisor:
                     self.step_fn = self.rebuild_fn()
                 state, step = self.ckpt.restore(
                     state, latest, shardings=self.state_shardings)
-        self.ckpt.save(step, state, blocking=True)
+        if self.ckpt is not None:
+            self.ckpt.save(step, state, blocking=True)
         return state, step

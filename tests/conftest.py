@@ -27,3 +27,16 @@ def _verify_plans():
 
     with plan_check.plan_verification():
         yield
+
+
+@pytest.fixture
+def tpu_interpret():
+    """Run Pallas kernels in Pallas's TPU interpreter.
+
+    The kernels always lower for the TPU; a CPU test that calls one asks
+    for the interpreter explicitly through this fixture.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    with pltpu.force_tpu_interpret_mode():
+        yield

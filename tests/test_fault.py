@@ -382,14 +382,14 @@ def test_supervisor_broadcasts_global_time_without_metrics(tmp_path):
 
 
 def _engine(prefill_fails=0, decode_fails=0, nan_logits=False,
-            max_retries=1):
+            max_retries=1, error=RuntimeError):
     V = 11
     calls = {"prefill": 0, "decode": 0}
 
     def prefill(toks, cache, pos, length):
         calls["prefill"] += 1
         if calls["prefill"] <= prefill_fails:
-            raise RuntimeError("injected prefill fault")
+            raise error("injected prefill fault")
         logits = jnp.full((1, toks.shape[1], V),
                           jnp.nan if nan_logits else 0.0)
         if not nan_logits:
@@ -399,7 +399,7 @@ def _engine(prefill_fails=0, decode_fails=0, nan_logits=False,
     def decode(toks, caches):
         calls["decode"] += 1
         if calls["decode"] <= decode_fails:
-            raise RuntimeError("injected decode fault")
+            raise error("injected decode fault")
         B = toks.shape[0]
         logits = jnp.zeros((B, 1, V)).at[..., 5].set(1.0)
         return logits, caches
@@ -454,6 +454,27 @@ def test_engine_screens_nonfinite_logits():
     assert not done[0].failed
     assert done[0].output[0] == 0          # all-NaN row degrades to token 0
     assert eng.fault_counters["nonfinite_logits"] >= 1
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_engine_device_error_escapes_injected_fault_retires(phase):
+    """A device error (OOM, lost chip) is not transient: it escapes run()
+    with nothing retried or retired; an injected fault is still retried,
+    then retired."""
+    fails = {f"{phase}_fails": 10 ** 6}
+    eng, calls = _engine(error=jax.errors.JaxRuntimeError, **fails)
+    _submit(eng, n=2)
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        eng.run()
+    assert calls[phase] == 1
+    assert not any(eng.fault_counters.values())
+
+    eng, calls = _engine(**fails)
+    _submit(eng, n=2)
+    done = eng.run()
+    assert len(done) == 2 and all(r.failed for r in done)
+    assert eng.fault_counters[f"{phase}_retries"] >= 1
+    assert eng.fault_counters["failed_requests"] == 2
 
 
 # ----------------------------------------------------- fallback-path lint --

@@ -224,7 +224,6 @@ def test_config_validation_at_construction():
 _HIER_SNIPPET = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.models.transformer import shard_map_compat
 from repro.core.balancer import BalancerConfig
 from repro.moe.gating import GatingConfig
 from repro.moe.layer import MoEConfig, MoEParams, moe_layer_local
@@ -257,7 +256,7 @@ def run_case(mesh, mode, racks, axis_name, ep_spec):
                  else jnp.zeros((3,), jnp.int32))
         return y, (stats.drops_dispatch + stats.drops_slot)[None], \\
                tiers[None]
-    f = shard_map_compat(run, mesh=mesh,
+    f = jax.shard_map(run, mesh=mesh, check_vma=False,
         in_specs=(P(ep_spec, None), P(None, None), P(ep_spec, None, None),
                   P(ep_spec, None, None), P(ep_spec, None, None)),
         out_specs=(P(ep_spec, None), P(ep_spec), P(ep_spec, None)))
@@ -324,13 +323,6 @@ print("RACK-INIT-OK", len(leaves))
 
 
 @pytest.mark.slow
-@pytest.mark.skip(reason=(
-    "full-LM train step on a virtual-device CPU mesh deadlocks in jax "
-    "0.4.37 (cross_module collective op-id divergence in the XLA CPU "
-    "runtime; see the matching skip in test_multidevice.py).  The hier "
-    "dispatch + two-stage replica streaming integration is covered by the "
-    "passing test_hier_2x4_bitwise_equals_flat and the replicated-mode "
-    "in-process test; re-enable alongside the flat full-model mesh test."))
 def test_hier_full_model_train_step_on_rack_mesh():
     """(1 data, 2 rack, 4 model) mesh: full LM train step with hier dispatch,
     loss finite and decreasing (multi-layer integration)."""
@@ -385,7 +377,6 @@ def test_hier_replicated_mode_on_rack_mesh_inprocess():
     from jax.sharding import PartitionSpec as P
 
     from repro.core.balancer import BalancerConfig
-    from repro.models.transformer import shard_map_compat
     from repro.moe.gating import GatingConfig
     from repro.moe.layer import MoEConfig, MoEParams, moe_layer_local
 
@@ -413,8 +404,8 @@ def test_hier_replicated_mode_on_rack_mesh_inprocess():
                 x, MoEParams(router, w1, w3, w2), cfg, axis_name=axis_name)
             return y, stats.drops_slot[None]
 
-        f = shard_map_compat(
-            run, mesh=mesh,
+        f = jax.shard_map(
+            run, mesh=mesh, check_vma=False,
             in_specs=(P(None, None), P(None, None), P(ep_spec, None, None),
                       P(ep_spec, None, None), P(ep_spec, None, None)),
             out_specs=(P(None, None), P(ep_spec)))

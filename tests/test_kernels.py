@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in the TPU
+interpreter."""
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,8 @@ from repro.kernels.grouped_gemm.ref import grouped_matmul_ref
 from repro.kernels.ssd_scan.ops import ssd_chunk_scan
 from repro.kernels.ssd_scan.ref import ssd_chunk_ref
 
+pytestmark = pytest.mark.usefixtures("tpu_interpret")
+
 
 @pytest.mark.parametrize("G,M,K,N", [
     (1, 128, 128, 128),
@@ -29,7 +32,7 @@ def test_grouped_gemm_sweep(G, M, K, N, dtype):
     kw = jax.random.PRNGKey(1)
     x = jax.random.normal(kx, (G, M, K), dtype)
     w = jax.random.normal(kw, (G, K, N), dtype)
-    out = grouped_matmul_pallas(x, w, bm=min(128, M), interpret=True)
+    out = grouped_matmul_pallas(x, w, bm=min(128, M))
     ref = grouped_matmul_ref(x, w)
     tol = 1e-4 if dtype == jnp.float32 else 2e-1
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -62,8 +65,7 @@ def test_flash_sweep(causal, S, d, bq, bk):
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, d)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, S, d)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, S, d)
-    out = flash_fwd_pallas(qf, kf, vf, causal=causal, bq=bq, bk=bk,
-                           interpret=True)
+    out = flash_fwd_pallas(qf, kf, vf, causal=causal, bq=bq, bk=bk)
     ref = attention_ref(q, k, v, causal=causal)
     ref = ref.transpose(0, 2, 1, 3).reshape(B * H, S, d)
     np.testing.assert_allclose(np.array(out), np.array(ref), rtol=1e-4,

@@ -14,7 +14,7 @@ Three independent directions of evidence:
   quantization tolerance of the fp32 path, and the reported ``tier_bytes``
   equal to ``tier_tokens`` times the wire payload width.
 
-Plus the w8a8 grouped-SwiGLU kernel (interpret mode on CPU) against its q8
+Plus the w8a8 grouped-SwiGLU kernel (TPU interpreter on CPU) against its q8
 jnp reference (bitwise) and the fp32 reference (tolerance).
 """
 
@@ -179,7 +179,7 @@ def _q8_operands(rng, G, M, K, N):
     return x, w, q, qs, wq, ws
 
 
-def test_grouped_matmul_q8_kernel_matches_ref(rng):
+def test_grouped_matmul_q8_kernel_matches_ref(rng, tpu_interpret):
     from repro.kernels.grouped_gemm import ops as gg
     from repro.kernels.grouped_gemm.ref import grouped_matmul_q8_ref
 
@@ -194,7 +194,7 @@ def test_grouped_matmul_q8_kernel_matches_ref(rng):
     assert err < 3e-2, err
 
 
-def test_grouped_swiglu_q8_kernel_matches_ref(rng):
+def test_grouped_swiglu_q8_kernel_matches_ref(rng, tpu_interpret):
     from repro.kernels.grouped_gemm import ops as gg
     from repro.kernels.grouped_gemm.ref import grouped_swiglu_q8_ref
 
@@ -283,7 +283,6 @@ def test_wire_dtype_requires_fused_dispatch():
 _WIRE_MESH_SNIPPET = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.models.transformer import shard_map_compat
 from repro.core.balancer import BalancerConfig
 from repro.moe.gating import GatingConfig
 from repro.moe.layer import MoEConfig, MoEParams, moe_layer_local
@@ -316,7 +315,7 @@ def run_case(wire, ffn):
         drops = (stats.drops_dispatch + stats.drops_slot)[None]
         return (y, drops, stats.counts[None], stats.tier_tokens[None],
                 stats.tier_bytes[None])
-    f = shard_map_compat(run, mesh=mesh,
+    f = jax.shard_map(run, mesh=mesh, check_vma=False,
         in_specs=(P(ep, None), P(None, None), P(ep, None, None),
                   P(ep, None, None), P(ep, None, None)),
         out_specs=(P(ep, None), P(ep), P(ep, None), P(ep, None),
@@ -357,7 +356,6 @@ def test_wire_dtypes_on_2x4_mesh():
 _REPLICA_WIRE_SNIPPET = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.models.transformer import shard_map_compat
 from repro.moe.distribute import materialize_replica_stack
 
 R, epr, D, F = 8, 2, 8, 12
@@ -380,7 +378,7 @@ def run(wire):
             [w1[0], w3[0], w2[0]], xs, my, "model", n_chunks=2,
             wire_dtype=wire)
         return tuple(o[None] for o in out)
-    f = shard_map_compat(body, mesh=mesh,
+    f = jax.shard_map(body, mesh=mesh, check_vma=False,
         in_specs=(P("model"), P("model"), P("model"), P(None, None)),
         out_specs=(P("model"), P("model"), P("model")))
     return [np.array(o) for o in jax.jit(f)(w1, w3, w2, x_slots)]

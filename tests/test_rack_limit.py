@@ -294,7 +294,6 @@ def test_resilience_relay_schedule_uses_live_speeds():
 _RACK_LIMIT_SNIPPET = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.models.transformer import shard_map_compat
 from repro.core.balancer import BalancerConfig
 from repro.moe.gating import GatingConfig
 from repro.moe.layer import MoEConfig, MoEParams, moe_layer_local
@@ -325,7 +324,7 @@ def run_case(gcfg):
         gt = (stats.gate_tier_tokens if stats.gate_tier_tokens is not None
               else -jnp.ones((3,), jnp.int32))
         return y, (stats.drops_dispatch + stats.drops_slot)[None], gt[None]
-    f = shard_map_compat(run, mesh=rack_mesh,
+    f = jax.shard_map(run, mesh=rack_mesh, check_vma=False,
         in_specs=(P(("rack", "model"), None), P(None, None),
                   P(("rack", "model"), None, None),
                   P(("rack", "model"), None, None),

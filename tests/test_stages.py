@@ -196,7 +196,6 @@ def test_overlap_bitwise_on_flat_mesh_inprocess():
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from repro.models.transformer import shard_map_compat
     from repro.moe.layer import MoEParams
 
     R = 8
@@ -223,8 +222,8 @@ def test_overlap_bitwise_on_flat_mesh_inprocess():
                 x, MoEParams(router, w1, w3, w2), cfg, axis_name="model")
             return y, (stats.drops_dispatch + stats.drops_slot)[None]
 
-        f = shard_map_compat(
-            run, mesh=mesh,
+        f = jax.shard_map(
+            run, mesh=mesh, check_vma=False,
             in_specs=(P("model", None), P(None, None), P("model", None, None),
                       P("model", None, None), P("model", None, None)),
             out_specs=(P("model", None), P("model")))
@@ -242,7 +241,6 @@ def test_overlap_bitwise_on_flat_mesh_inprocess():
 _OVERLAP_SNIPPET = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.models.transformer import shard_map_compat
 from repro.core.balancer import BalancerConfig
 from repro.moe.gating import GatingConfig
 from repro.moe.layer import MoEConfig, MoEParams, moe_layer_local
@@ -273,7 +271,7 @@ def run_case(mode, overlap, tok_spec):
             axis_name=("rack", "model"))
         return y, (stats.drops_dispatch + stats.drops_slot)[None]
     ep = ("rack", "model")
-    f = shard_map_compat(run, mesh=mesh,
+    f = jax.shard_map(run, mesh=mesh, check_vma=False,
         in_specs=(P(tok_spec, None), P(None, None), P(ep, None, None),
                   P(ep, None, None), P(ep, None, None)),
         out_specs=(P(tok_spec, None), P(ep)))
