@@ -21,8 +21,9 @@ from repro.models.transformer import (
     segments_for,
 )
 
-__all__ = ["LMParams", "init_lm", "init_router_bias", "forward", "lm_loss",
-           "blocked_lm_loss", "init_caches", "decode_step", "param_count"]
+__all__ = ["LMParams", "MoECounters", "init_lm", "init_router_bias",
+           "forward", "lm_loss", "blocked_lm_loss", "init_caches",
+           "prefill_step", "decode_step", "param_count"]
 
 
 class LMParams(NamedTuple):
@@ -31,6 +32,14 @@ class LMParams(NamedTuple):
     segments: tuple                       # stacked BlockParams per segment
     final_norm: jax.Array                 # (D,)
     lm_head: jax.Array | None             # (V, D); None = tied
+
+
+class MoECounters(NamedTuple):
+    """Routed (token, expert) pairs of one step, per layer ((num_layers,)
+    int32 each; zero on layers without experts)."""
+
+    held: jax.Array      # pairs the layer's expert slots held
+    drops: jax.Array     # pairs dropped at pair or slot capacity
 
 
 def _stack_blocks(blocks: list[BlockParams]) -> BlockParams:
@@ -116,7 +125,9 @@ def forward(
     """
     from repro.models.transformer import wsc
 
-    x = wsc(_input_embeddings(params, batch, cfg), pctx, "seq")
+    with jax.named_scope("embed"):
+        x = _input_embeddings(params, batch, cfg)
+    x = wsc(x, pctx, "seq")
     segs = segments_for(cfg, rcfg)
     aux_tot = jnp.zeros((), jnp.float32)
     drops_tot = jnp.zeros((), jnp.int32)
@@ -129,17 +140,25 @@ def forward(
         x, aux, drops, counts, _ = segment_apply(
             x, seg, sp, cfg, rcfg, pctx, router_bias=bias_seg)
         aux_tot += aux
-        drops_tot += drops
+        drops_tot += drops.sum()
         counts_all = jax.lax.dynamic_update_slice_in_dim(
             counts_all, counts.astype(jnp.int32), seg.layer_ids[0], axis=0)
-    x = rms_norm(x, params.final_norm)
     if return_hidden:
+        with jax.named_scope("head"):
+            x = rms_norm(x, params.final_norm)
         return x, aux_tot, drops_tot, counts_all
-    head = params.embedding if params.lm_head is None else params.lm_head
     # Seq-sharded fp32 logits: softmax/CE are then token-local (no vocab
     # collective in the loss).
-    logits = wsc(unembed(x, head), pctx, "seq")
+    logits = wsc(_head(params, x), pctx, "seq")
     return logits, aux_tot, drops_tot, counts_all
+
+
+def _head(params: LMParams, x: jax.Array) -> jax.Array:
+    """Final norm and unembedding, under the named scope ``head``."""
+    with jax.named_scope("head"):
+        x = rms_norm(x, params.final_norm)
+        head = params.embedding if params.lm_head is None else params.lm_head
+        return unembed(x, head)
 
 
 def lm_loss(logits: jax.Array, targets: jax.Array,
@@ -212,6 +231,30 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int, rcfg: RuntimeConfig)
     return tuple(caches)
 
 
+def _cached_step(params: LMParams, caches, tokens: jax.Array,
+                 cfg: ModelConfig, rcfg: RuntimeConfig, pctx: ParallelCtx, *,
+                 decode: bool, valid_len, router_bias):
+    """(logits, new_caches, MoECounters) of a step that reads and writes
+    the caches."""
+    with jax.named_scope("embed"):
+        x = embed(tokens, params.embedding)
+    segs = segments_for(cfg, rcfg)
+    new_caches, held, drops_all = [], [], []
+    for seg, sp, cache in zip(segs, params.segments, caches):
+        bias_seg = None
+        if router_bias is not None:
+            bias_seg = router_bias[jnp.array(seg.layer_ids)]
+        x, _aux, drops, counts, nc = segment_apply(
+            x, seg, sp, cfg, rcfg, pctx, caches=cache,
+            router_bias=bias_seg, decode=decode, valid_len=valid_len)
+        new_caches.append(nc)
+        held.append(counts.sum(axis=-1, dtype=jnp.int32) - drops)
+        drops_all.append(drops)
+    counters = MoECounters(held=jnp.concatenate(held),
+                           drops=jnp.concatenate(drops_all))
+    return _head(params, x), tuple(new_caches), counters
+
+
 def prefill_step(
     params: LMParams,
     caches,
@@ -225,23 +268,12 @@ def prefill_step(
 ):
     """Chunked prefill: run a (B, C) chunk, writing caches at their offset.
 
-    Returns (logits, new_caches).  The chunk's absolute position comes from
-    the caches' ``length`` counters.
+    Returns (logits, new_caches, MoECounters).  The chunk's absolute
+    position comes from the caches' ``length`` counters.
     """
-    x = embed(tokens, params.embedding)
-    segs = segments_for(cfg, rcfg)
-    new_caches = []
-    for seg, sp, cache in zip(segs, params.segments, caches):
-        bias_seg = None
-        if router_bias is not None:
-            bias_seg = router_bias[jnp.array(seg.layer_ids)]
-        x, _aux, _drops, _counts, nc = segment_apply(
-            x, seg, sp, cfg, rcfg, pctx, caches=cache,
-            router_bias=bias_seg, decode=False, valid_len=valid_len)
-        new_caches.append(nc)
-    x = rms_norm(x, params.final_norm)
-    head = params.embedding if params.lm_head is None else params.lm_head
-    return unembed(x, head), tuple(new_caches)
+    return _cached_step(params, caches, tokens, cfg, rcfg, pctx,
+                        decode=False, valid_len=valid_len,
+                        router_bias=router_bias)
 
 
 def decode_step(
@@ -254,22 +286,10 @@ def decode_step(
     *,
     router_bias: jax.Array | None = None,
 ):
-    """One-token decode.  tokens: (B, 1).  Returns (logits, new_caches)."""
-    x = embed(tokens, params.embedding)
-    segs = segments_for(cfg, rcfg)
-    new_caches = []
-    for seg, sp, cache in zip(segs, params.segments, caches):
-        bias_seg = None
-        if router_bias is not None:
-            bias_seg = router_bias[jnp.array(seg.layer_ids)]
-        x, _aux, _drops, _counts, nc = segment_apply(
-            x, seg, sp, cfg, rcfg, pctx, caches=cache,
-            router_bias=bias_seg, decode=True)
-        new_caches.append(nc)
-    x = rms_norm(x, params.final_norm)
-    head = params.embedding if params.lm_head is None else params.lm_head
-    logits = unembed(x, head)
-    return logits, tuple(new_caches)
+    """One-token decode.  tokens: (B, 1).  Returns (logits, new_caches,
+    MoECounters)."""
+    return _cached_step(params, caches, tokens, cfg, rcfg, pctx,
+                        decode=True, valid_len=None, router_bias=router_bias)
 
 
 def param_count(params) -> int:

@@ -42,8 +42,8 @@ from repro.moe.layer import (
 
 __all__ = ["RuntimeConfig", "ParallelCtx", "BlockParams", "Segment",
            "build_segments", "segments_for", "segment_apply", "attn_config",
-           "ssm_config", "moe_config", "effective_rack_limit", "init_block",
-           "init_cache_block"]
+           "ssm_config", "moe_config", "block_moe_config", "moe_slot_rows",
+           "effective_rack_limit", "init_block", "init_cache_block"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -252,6 +252,32 @@ def moe_config(cfg: ModelConfig, rcfg: RuntimeConfig, pctx: ParallelCtx,
     )
 
 
+def block_moe_config(cfg: ModelConfig, rcfg: RuntimeConfig,
+                     pctx: ParallelCtx, batch: int, seq: int, *,
+                     decode: bool = False) -> MoEConfig:
+    """The MoEConfig a block's MoE layer runs with on a (batch, seq) input:
+    decode replicates its tokens over the EP group, every other mode
+    shards the sequence over it."""
+    tokens_per_rank = max(
+        1, (batch // pctx.batch_size_divisor)
+        * (seq if decode or seq < pctx.ep_size else seq // pctx.ep_size))
+    return moe_config(cfg, rcfg, pctx, tokens_per_rank,
+                      dispatch_mode="replicated" if decode else "a2a")
+
+
+def moe_slot_rows(cfg: ModelConfig, rcfg: RuntimeConfig, pctx: ParallelCtx,
+                  batch: int, seq: int, *, decode: bool = False) -> int:
+    """Slot rows the expert FFN of one MoE layer runs on a (batch, seq)
+    input, summed over the devices: each runs ``overlap_chunks`` x
+    ``num_slots`` x ``cap_slot`` rows, whether a routed pair fills a row
+    or not."""
+    m = block_moe_config(cfg, rcfg, pctx, batch, seq, decode=decode)
+    devices = 1 if pctx.mesh is None else (pctx.batch_size_divisor
+                                           * pctx.ep_size)
+    slots = m.layout.experts_per_rank + m.layout.n_slot
+    return devices * m.overlap_chunks * slots * m.cap_slot
+
+
 def _pattern_period(cfg: ModelConfig) -> tuple[int, int]:
     """(prefix, period) of the layer-kind pattern."""
     import math
@@ -436,6 +462,40 @@ def _ep_moe_block(x: jax.Array, mp: MoEParams, mcfg: MoEConfig,
     return y, aux.sum(), drops.sum(), counts
 
 
+def _attention(h: jax.Array, bp: BlockParams, cfg: ModelConfig,
+               rcfg: RuntimeConfig, *, cache, decode: bool, valid_len):
+    """The attention mixer of a block, with its cache write, under the
+    named scope ``attn``.  Returns (output, new cache)."""
+    acfg = attn_config(cfg)
+    new_cache = cache
+    with jax.named_scope("attn"):
+        if decode:
+            if cfg.is_mla:
+                att, new_cache = attn_mod.mla_decode(h, cache, bp.attn, acfg)
+            else:
+                att, new_cache = attn_mod.gqa_decode(
+                    h, cache, bp.attn, acfg, block_kv=rcfg.block_kv,
+                    unroll=rcfg.analysis_unroll)
+        elif cache is not None:  # chunked prefill writes the cache
+            if cfg.is_mla:
+                att, new_cache = attn_mod.mla_prefill(
+                    h, cache, bp.attn, acfg, valid_len=valid_len,
+                    block_kv=rcfg.block_kv, unroll=rcfg.analysis_unroll)
+            else:
+                att, new_cache = attn_mod.gqa_prefill(
+                    h, cache, bp.attn, acfg, valid_len=valid_len,
+                    block_kv=rcfg.block_kv, unroll=rcfg.analysis_unroll)
+        elif cfg.is_mla:
+            att = attn_mod.mla_attention(h, bp.attn, acfg,
+                                         block_kv=rcfg.block_kv,
+                                         unroll=rcfg.analysis_unroll)
+        else:
+            att = attn_mod.gqa_attention(h, bp.attn, acfg,
+                                         block_kv=rcfg.block_kv,
+                                         unroll=rcfg.analysis_unroll)
+    return att, new_cache
+
+
 def block_apply(
     x: jax.Array,
     bp: BlockParams,
@@ -453,7 +513,9 @@ def block_apply(
 
     Modes: train/full forward (cache None), chunked prefill (cache given,
     decode False -- writes the cache at offset cache.length), decode
-    (cache given, decode True, S == 1).
+    (cache given, decode True, S == 1).  Attention (with its cache write)
+    runs under the named scope ``attn`` and a dense FFN under
+    ``ffn.dense``; the MoE stages name their own (``moe.*``).
     """
     mixer, ffn_kind = kind.split("+")
     aux = jnp.zeros((), jnp.float32)
@@ -467,32 +529,8 @@ def block_apply(
         # Sequence parallelism: gather S at mixer entry (heads shard over
         # the model axis inside), reduce-scatter back to seq-sharded.
         h = wsc(h, pctx, "full", decode=decode)
-        acfg = attn_config(cfg)
-        if decode:
-            if cfg.is_mla:
-                att, new_cache = attn_mod.mla_decode(h, cache, bp.attn, acfg)
-            else:
-                att, new_cache = attn_mod.gqa_decode(
-                    h, cache, bp.attn, acfg, block_kv=rcfg.block_kv,
-                    unroll=rcfg.analysis_unroll)
-        elif cache is not None:  # chunked prefill writes the cache
-            if cfg.is_mla:
-                att, new_cache = attn_mod.mla_prefill(
-                    h, cache, bp.attn, acfg, valid_len=valid_len,
-                    block_kv=rcfg.block_kv, unroll=rcfg.analysis_unroll)
-            else:
-                att, new_cache = attn_mod.gqa_prefill(
-                    h, cache, bp.attn, acfg, valid_len=valid_len,
-                    block_kv=rcfg.block_kv, unroll=rcfg.analysis_unroll)
-        else:
-            if cfg.is_mla:
-                att = attn_mod.mla_attention(h, bp.attn, acfg,
-                                             block_kv=rcfg.block_kv,
-                                             unroll=rcfg.analysis_unroll)
-            else:
-                att = attn_mod.gqa_attention(h, bp.attn, acfg,
-                                             block_kv=rcfg.block_kv,
-                                             unroll=rcfg.analysis_unroll)
+        att, new_cache = _attention(h, bp, cfg, rcfg, cache=cache,
+                                    decode=decode, valid_len=valid_len)
         x = x + wsc(att, pctx, "seq", decode=decode)
     else:
         scfg = ssm_config(cfg)
@@ -512,20 +550,15 @@ def block_apply(
         h2 = rms_norm(x, bp.norm2)
         if ffn_kind == "moe":
             B, S, _ = x.shape
-            tokens_per_rank = max(
-                1, (B // pctx.batch_size_divisor)
-                * (S if decode or S < pctx.ep_size else S // pctx.ep_size)
-            )
-            mcfg = moe_config(
-                cfg, rcfg, pctx, tokens_per_rank,
-                dispatch_mode="replicated" if decode else "a2a",
-            )
+            mcfg = block_moe_config(cfg, rcfg, pctx, B, S, decode=decode)
             y2, aux, drops, counts = _ep_moe_block(h2, bp.moe, mcfg, pctx,
                                                    router_bias)
         else:
             # Dense FFN: gather S, hidden shards over model, scatter back.
             h2 = wsc(h2, pctx, "full", decode=decode)
-            y2 = wsc(dense_swiglu(h2, *bp.ffn), pctx, "seq", decode=decode)
+            with jax.named_scope("ffn.dense"):
+                y2 = dense_swiglu(h2, *bp.ffn)
+            y2 = wsc(y2, pctx, "seq", decode=decode)
         x = x + y2
     return x, aux, drops, counts, new_cache
 
@@ -545,10 +578,10 @@ def segment_apply(
 ):
     """Run one homogeneous segment (scan if stacked, loop otherwise).
 
-    Returns (x, aux_sum, drops_sum, counts (L_seg, E), new_caches).
+    Returns (x, aux_sum, drops (L_seg,), counts (L_seg, E), new_caches):
+    the routed pairs each layer dropped and its per-expert load.
     """
     aux_tot = jnp.zeros((), jnp.float32)
-    drops_tot = jnp.zeros((), jnp.int32)
 
     if seg.kind == "cycle":
         # Heterogeneous repeating period: scan over cycle repetitions with
@@ -559,7 +592,7 @@ def segment_apply(
 
         def body(x, layer_in):
             aux_c = jnp.zeros((), jnp.float32)
-            drops_c = jnp.zeros((), jnp.int32)
+            drops_c = []
             counts_c = []
             nc_list = []
             for j, kind_j in enumerate(seg.cycle):
@@ -578,10 +611,10 @@ def segment_apply(
                 x, aux, drops, counts, ncj = run(x, layer_in["p"][j],
                                                  cache_j, bias_j)
                 aux_c += aux
-                drops_c += drops
+                drops_c.append(drops)
                 counts_c.append(counts)
                 nc_list.append(ncj)
-            outs = {"aux": aux_c, "drops": drops_c,
+            outs = {"aux": aux_c, "drops": jnp.stack(drops_c),
                     "counts": jnp.stack(counts_c)}
             if caches is not None:
                 outs["cache"] = tuple(nc_list)
@@ -594,8 +627,8 @@ def segment_apply(
             ins["bias"] = router_bias.reshape(seg.n_cycles, p, -1)
         x, outs = jax.lax.scan(body, x, ins)
         counts = outs["counts"].reshape(seg.length, -1)
-        return (x, outs["aux"].sum(), outs["drops"].sum(), counts,
-                outs.get("cache"))
+        return (x, outs["aux"].sum(), outs["drops"].reshape(seg.length),
+                counts, outs.get("cache"))
 
     stacked = isinstance(params, BlockParams)  # stacked leaves (L, ...)
     if stacked and rcfg.scan_layers and seg.length >= rcfg.min_scan_len:
@@ -624,8 +657,7 @@ def segment_apply(
             ins["bias"] = router_bias
         x, outs = jax.lax.scan(body, x, ins)
         aux_tot += outs["aux"].sum()
-        drops_tot += outs["drops"].sum()
-        return x, aux_tot, drops_tot, outs["counts"], outs.get("cache")
+        return x, aux_tot, outs["drops"], outs["counts"], outs.get("cache")
 
     # Unstacked / short segment: python loop.
     if stacked:
@@ -634,6 +666,7 @@ def segment_apply(
     else:
         plist = list(params)
     new_caches = []
+    drops_l = []
     counts_l = []
     for i, bp in enumerate(plist):
         cache_l = None
@@ -651,13 +684,14 @@ def segment_apply(
             run_block = jax.checkpoint(run_block, prevent_cse=False)
         x, aux, drops, counts, nc = run_block(x, bp, cache_l, bias_l)
         aux_tot += aux
-        drops_tot += drops
+        drops_l.append(drops)
         counts_l.append(counts)
         new_caches.append(nc)
+    drops_seg = jnp.stack(drops_l) if drops_l else jnp.zeros((0,), jnp.int32)
     counts_seg = jnp.stack(counts_l) if counts_l else jnp.zeros(
         (0, 1), jnp.int32)
     if caches is None:
         new_caches = None
     elif not isinstance(caches, (list, tuple)):
         new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)
-    return x, aux_tot, drops_tot, counts_seg, new_caches
+    return x, aux_tot, drops_seg, counts_seg, new_caches

@@ -111,14 +111,13 @@ class MoEStats(NamedTuple):
                                 #    per tier = tier_tokens * the per-item
                                 #    payload width of cfg.wire_dtype
                                 #    (repro.core.quantize, DESIGN.md S12)
-    # At-gate twins of tier_tokens/tier_bytes (rack-aware non-replicated
-    # modes; DESIGN.md S14): deduplicated payload copies measured at the
+    # At-gate twin of tier_tokens (rack-aware non-replicated modes;
+    # DESIGN.md S14): deduplicated payload copies measured at the
     # gate against the home placement, BEFORE the plan's reroute --
     # gate_tier_tokens[2] is the aggregated hop-1 volume an M-rack-limited
     # gate bounds to <= M copies per token, vs tier_tokens[2] which is what
     # the solved plan actually ships (in items).
     gate_tier_tokens: jax.Array | None = None  # (3,) [local, intra, inter]
-    gate_tier_bytes: jax.Array | None = None   # (3,) copies * payload width
     # Resilience counters (populated when run with a Resilience; DESIGN.md
     # S13).  fallback_plans counts degradation-ladder activations of THIS
     # call (solve -> last-good -> no-balance, plus transfer-exhaustion
@@ -775,25 +774,35 @@ def run_staged_moe(
     at the stage boundaries (corrupted rows dropped + counted, never
     propagated to the residual stream); and the new ``MoEStats`` fault
     counters report what happened.
+
+    Each stage runs under a named scope (``moe.gate``, ``moe.plan``,
+    ``moe.distribute``, ``moe.dispatch``, ``moe.ffn``, ``moe.combine``,
+    ``moe.shared``), so a profile puts the device's time down to a stage.
     """
     T, D = x.shape
     ctx = make_stage_ctx(cfg, axis_name)
     res = resilience
     fallback_before = (0 if res is None
                        else res.counters["fallback_plans"])
-    gs = gate_stage(ctx, x, params.router, router_bias)
-    ps = plan_stage(ctx, gs, lam_e_est=lam_e_est, resilience=res)
-    ps, dist = _distribute_with_ladder(ctx, params, gs, ps, res)
+    with jax.named_scope("moe.gate"):
+        gs = gate_stage(ctx, x, params.router, router_bias)
+    with jax.named_scope("moe.plan"):
+        ps = plan_stage(ctx, gs, lam_e_est=lam_e_est, resilience=res)
+    with jax.named_scope("moe.distribute"):
+        ps, dist = _distribute_with_ladder(ctx, params, gs, ps, res)
 
     C = cfg.overlap_chunks
     if T % C != 0:
         raise ValueError(
             f"overlap_chunks={C} must divide the local token count T={T}")
     bounds = chunk_bounds(T, n_chunks=C)
-    offsets = (chunk_occ_offsets(gs.gate_out.expert_ids, C,
-                                 cfg.gating.num_experts) if C > 1 else None)
+    with jax.named_scope("moe.dispatch"):
+        offsets = (chunk_occ_offsets(gs.gate_out.expert_ids, C,
+                                     cfg.gating.num_experts)
+                   if C > 1 else None)
     screening = res is not None and res.cfg.screen_payloads
 
+    @jax.named_scope("moe.dispatch")
     def disp(i: int) -> DispatchState:
         s, ln = bounds[i]
         off = offsets[i] if offsets is not None else None
@@ -815,33 +824,40 @@ def run_staged_moe(
         # i's buffers, then retire chunk i with FFN + combine.
         d_cur, d_next = d_next, (disp(i + 1) if i + 1 < C else None)
         if screening:
-            xs, valid, n_bad = screen_payload(d_cur.xs, d_cur.valid)
+            with jax.named_scope("moe.dispatch"):
+                xs, valid, n_bad = screen_payload(d_cur.xs, d_cur.valid)
             d_cur = d_cur._replace(xs=xs, valid=valid)
             dropped_payload = dropped_payload + n_bad
-        out = compute_stage(ctx, d_cur, dist)
+        with jax.named_scope("moe.ffn"):
+            out = compute_stage(ctx, d_cur, dist)
         s, ln = bounds[i]
-        y_chunk = combine_stage(ctx, d_cur, out,
-                                gs.gate_out.weights[s:s + ln])
-        if screening:
-            y_chunk, n_bad = _screen_rows(y_chunk)
-            dropped_payload = dropped_payload + n_bad
+        with jax.named_scope("moe.combine"):
+            y_chunk = combine_stage(ctx, d_cur, out,
+                                    gs.gate_out.weights[s:s + ln])
+            if screening:
+                y_chunk, n_bad = _screen_rows(y_chunk)
+                dropped_payload = dropped_payload + n_bad
         ys.append(y_chunk)
         drops_dispatch = drops_dispatch + d_cur.drops_dispatch
         drops_slot = drops_slot + d_cur.drops_slot
         max_slot_load = jnp.maximum(
             max_slot_load, d_cur.valid.sum(axis=1).max().astype(_I32))
-    y = ys[0] if C == 1 else jnp.concatenate(ys, axis=0)
-
-    if cfg.dispatch_mode == "replicated":
-        # One rank-merge over the whole batch: psum is elementwise, so the
-        # merged concat equals the concat of per-chunk merges bitwise.
-        if ctx.factored:
-            y = jax.lax.psum(jax.lax.psum(y, ctx.lane_axis), ctx.rack_axis)
-        elif ctx.axis_name is not None:
-            y = jax.lax.psum(y, ctx.axis_name)
+    with jax.named_scope("moe.combine"):
+        y = ys[0] if C == 1 else jnp.concatenate(ys, axis=0)
+        if cfg.dispatch_mode == "replicated":
+            # One rank-merge over the whole batch: psum is elementwise, so
+            # the merged concat equals the concat of per-chunk merges
+            # bitwise.
+            if ctx.factored:
+                y = jax.lax.psum(jax.lax.psum(y, ctx.lane_axis),
+                                 ctx.rack_axis)
+            elif ctx.axis_name is not None:
+                y = jax.lax.psum(y, ctx.axis_name)
 
     if cfg.n_shared_experts > 0:
-        y = y + swiglu(x, params.shared_w1, params.shared_w3, params.shared_w2)
+        with jax.named_scope("moe.shared"):
+            y = y + swiglu(x, params.shared_w1, params.shared_w3,
+                           params.shared_w2)
 
     tier_bytes = None
     if ps.plan.tier_tokens is not None:
@@ -850,10 +866,6 @@ def run_staged_moe(
         # 4 in-band scale bytes per row).  Shares its width definition with
         # the host cost model and the static verifier via repro.core.quantize.
         tier_bytes = ps.plan.tier_tokens * payload_bytes_per_item(
-            D, cfg.wire_dtype, base_bytes=x.dtype.itemsize)
-    gate_tier_bytes = None
-    if ps.plan.gate_tier_tokens is not None:
-        gate_tier_bytes = ps.plan.gate_tier_tokens * payload_bytes_per_item(
             D, cfg.wire_dtype, base_bytes=x.dtype.itemsize)
 
     fallbacks = quarantined = None
@@ -872,7 +884,6 @@ def run_staged_moe(
         tier_replicas=ps.plan.tier_replicas,
         tier_bytes=tier_bytes,
         gate_tier_tokens=ps.plan.gate_tier_tokens,
-        gate_tier_bytes=gate_tier_bytes,
         fallback_plans=fallbacks,
         dropped_payload_tokens=(dropped_payload if res is not None else None),
         quarantined_ranks=quarantined,

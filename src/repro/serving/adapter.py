@@ -2,12 +2,15 @@
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro import tracing
+from repro.configs.base import ModelConfig, layer_kinds
 from repro.models.model import LMParams, decode_step, init_caches, prefill_step
-from repro.models.transformer import ParallelCtx, RuntimeConfig
+from repro.models.transformer import ParallelCtx, RuntimeConfig, moe_slot_rows
 
 __all__ = ["make_engine_fns"]
 
@@ -18,7 +21,10 @@ def make_engine_fns(params: LMParams, cfg: ModelConfig, rcfg: RuntimeConfig,
     unstack_caches).
 
     ``params`` enter the jitted steps as arguments: closed over, they would
-    be baked into each program as constants.
+    be baked into each program as constants.  The steps' MoE counters go
+    to ``repro.tracing.count`` while a trace records, with the top-k pairs
+    of the valid tokens (a prefill chunk less its right-padding), and are
+    dropped otherwise.
     """
 
     @jax.jit
@@ -30,12 +36,36 @@ def make_engine_fns(params: LMParams, cfg: ModelConfig, rcfg: RuntimeConfig,
     def _decode(params, tokens, caches):
         return decode_step(params, caches, tokens, cfg, rcfg, pctx)
 
+    moe_layers = tuple(kind.endswith("+moe") for kind in layer_kinds(cfg))
+
+    @functools.cache
+    def slot_rows(batch, seq, decode):
+        """Slot rows each layer's expert FFN runs per call (0: no experts)."""
+        return tuple(moe_slot_rows(cfg, rcfg, pctx, batch, seq, decode=decode)
+                     if moe else 0 for moe in moe_layers)
+
+    def valid_pairs(tokens):
+        """Top-k pairs of ``tokens`` valid tokens, per layer."""
+        return tuple(tokens * cfg.moe.top_k if moe else 0
+                     for moe in moe_layers)
+
     def prefill_fn(tokens, caches, start, valid_len):
-        return _prefill(params, tokens, caches,
-                        jnp.asarray(valid_len, jnp.int32))
+        logits, caches, counters = _prefill(
+            params, tokens, caches, jnp.asarray(valid_len, jnp.int32))
+        if tracing.active():
+            batch, seq = tokens.shape
+            tracing.count("prefill", counters, slot_rows(batch, seq, False),
+                          valid_pairs(batch * int(valid_len)))
+        return logits, caches
 
     def decode_fn(tokens, caches):
-        return _decode(params, tokens, caches)
+        logits, caches, counters = _decode(params, tokens, caches)
+        if tracing.active():
+            # A short decode batch repeats a real row, which the counters
+            # cannot tell from it: every row counts as valid.
+            tracing.count("decode", counters, slot_rows(*tokens.shape, True),
+                          valid_pairs(tokens.size))
+        return logits, caches
 
     def new_cache_fn(batch):
         return init_caches(cfg, batch, max_seq, rcfg)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.moe.stages import chunk_bounds
 
 __all__ = ["EngineConfig", "Request", "ServingEngine"]
@@ -61,6 +62,13 @@ class ServingEngine:
     cache for decode slots; a virtual clock advances by the measured or
     supplied per-call latency so TTFT/TPOT statistics work both for real
     execution and for analytic replay.
+
+    While a profiler trace records, the host work opens ``repro.tracing``
+    spans: ``engine.schedule`` (one ``run`` iteration), ``engine.prefill``
+    (a request) > ``engine.new_cache``, ``engine.prefill_chunk``;
+    ``engine.sample`` (device->host read and argmax), ``engine.decode``,
+    ``engine.stack_caches``, ``engine.unstack_caches``.  A request's spans
+    carry its ``rid``, a decode batch's its rows ``n``.
     """
 
     def __init__(self, cfg: EngineConfig, *, prefill_fn: Callable,
@@ -124,19 +132,23 @@ class ServingEngine:
         chunk is right-padded, so its last real token sits at ``length - 1``
         and not at the end of the chunk.
         """
-        cache = self.new_cache_fn(1)
-        last_logits = None
-        # Same chunking helper as the MoE overlap driver
-        # (repro.moe.stages): fixed-size spans, ragged tail.
-        for pos, length in chunk_bounds(
-                len(req.prompt), chunk_size=self.cfg.chunk_size):
-            chunk = req.prompt[pos: pos + length]
-            pad = self.cfg.chunk_size - length
-            toks = np.pad(chunk, (0, pad))[None, :]
-            logits, cache = self.prefill_fn(
-                jnp.asarray(toks, jnp.int32), cache, pos, length)
-            last_logits = logits[0, length - 1]
-            self._advance(self.clock_fn() if self.clock_fn else 0.0)
+        with tracing.span("engine.prefill", rid=req.rid):
+            with tracing.span("engine.new_cache", rid=req.rid):
+                cache = self.new_cache_fn(1)
+            last_logits = None
+            # Same chunking helper as the MoE overlap driver
+            # (repro.moe.stages): fixed-size spans, ragged tail.
+            for pos, length in chunk_bounds(
+                    len(req.prompt), chunk_size=self.cfg.chunk_size):
+                with tracing.span("engine.prefill_chunk", rid=req.rid,
+                                  pos=pos):
+                    chunk = req.prompt[pos: pos + length]
+                    pad = self.cfg.chunk_size - length
+                    toks = np.pad(chunk, (0, pad))[None, :]
+                    logits, cache = self.prefill_fn(
+                        jnp.asarray(toks, jnp.int32), cache, pos, length)
+                    last_logits = logits[0, length - 1]
+                    self._advance(self.clock_fn() if self.clock_fn else 0.0)
         return last_logits, cache
 
     def run(self, until_empty: bool = True):
@@ -150,81 +162,96 @@ class ServingEngine:
         memory, a failed or lost device) is not transient and escapes.
         """
         while self.waiting or self.decoding:
-            # 1. Prefill the oldest waiting request, chunk by chunk.
-            if self.waiting:
-                req = self.waiting.popleft()
-                if self.now < req.arrival:
-                    self.now = req.arrival
-                last_logits = cache = None
-                for attempt in range(self.cfg.max_retries + 1):
-                    try:
-                        last_logits, cache = self.prefill(req)
-                        break
-                    except jax.errors.JaxRuntimeError:
-                        raise
-                    except RuntimeError:
-                        # Retry the whole prefill; the chunk loop mutates
-                        # only local state so a clean restart is safe.
-                        if attempt == self.cfg.max_retries:
-                            self._fail(req)
-                        else:
-                            self.fault_counters["prefill_retries"] += 1
-                if last_logits is not None:
-                    req.first_token_at = self.now
-                    # Host-side scheduling layer (module docstring): reading
-                    # results back is the point, never under jit.
-                    first = self._argmax_token(np.asarray(last_logits))  # uep-lint: disable=host-sync
-                    req.output = [first]
-                    self.decoding.append((req, cache))
-
-            # 2. One decode step over all active slots (batched).
-            if self.decoding and (len(self.decoding) >= self.cfg.decode_batch
-                                  or not self.waiting):
-                group = self.decoding[: self.cfg.decode_batch]
-                # A short group is padded with copies of its first row, so
-                # decode always runs at one shape and compiles once.
-                pad = self.cfg.decode_batch - len(group)
-                toks = np.array([[r.output[-1]] for r, _ in group]  # uep-lint: disable=host-sync
-                                + [[group[0][0].output[-1]]] * pad, np.int32)
-                caches = self.stack_caches([c for _, c in group]
-                                           + [group[0][1]] * pad)
-                logits = None
-                for attempt in range(self.cfg.max_retries + 1):
-                    try:
-                        logits, caches = self.decode_fn(jnp.asarray(toks),
-                                                        caches)
-                        break
-                    except jax.errors.JaxRuntimeError:
-                        raise
-                    except RuntimeError:
-                        if attempt == self.cfg.max_retries:
-                            # Retire the whole group: a decode step that
-                            # keeps faulting must not wedge the queue.
-                            for r, _ in group:
-                                self._fail(r)
-                            self.decoding = self.decoding[
-                                self.cfg.decode_batch:]
-                        else:
-                            self.fault_counters["decode_retries"] += 1
-                if logits is None:
-                    continue
-                self._advance(self.clock_fn() if self.clock_fn else 0.0)
-                logits_np = np.asarray(logits[:, -1])  # uep-lint: disable=host-sync
-                still = []
-                for i, (r, _) in enumerate(group):
-                    r.output.append(self._argmax_token(logits_np[i]))
-                    if len(r.output) >= r.max_new_tokens:
-                        r.done_at = self.now
-                        self.finished.append(r)
-                    else:
-                        still.append(i)
-                new_caches = self.unstack_caches(caches, len(group))
-                self.decoding = (
-                    [(group[i][0], new_caches[i]) for i in still]
-                    + self.decoding[self.cfg.decode_batch:])
-            if not until_empty:
+            with tracing.span("engine.schedule"):
+                retired = not self._step()
+            # A decode group retired on faults goes straight on to the
+            # next iteration.
+            if not until_empty and not retired:
                 break
         return self.finished
+
+    def _step(self) -> bool:
+        """One iteration of :meth:`run`: prefill the oldest waiting
+        request, then one decode step over the active slots.  False where
+        the decode group was retired on faults."""
+        # 1. Prefill the oldest waiting request, chunk by chunk.
+        if self.waiting:
+            req = self.waiting.popleft()
+            if self.now < req.arrival:
+                self.now = req.arrival
+            last_logits = cache = None
+            for attempt in range(self.cfg.max_retries + 1):
+                try:
+                    last_logits, cache = self.prefill(req)
+                    break
+                except jax.errors.JaxRuntimeError:
+                    raise
+                except RuntimeError:
+                    # Retry the whole prefill; the chunk loop mutates
+                    # only local state so a clean restart is safe.
+                    if attempt == self.cfg.max_retries:
+                        self._fail(req)
+                    else:
+                        self.fault_counters["prefill_retries"] += 1
+            if last_logits is not None:
+                req.first_token_at = self.now
+                # Host-side scheduling layer (module docstring): reading
+                # results back is the point, never under jit.
+                with tracing.span("engine.sample", rid=req.rid):
+                    first = self._argmax_token(np.asarray(last_logits))  # uep-lint: disable=host-sync
+                req.output = [first]
+                self.decoding.append((req, cache))
+
+        # 2. One decode step over all active slots (batched).
+        if not self.decoding or (len(self.decoding) < self.cfg.decode_batch
+                                 and self.waiting):
+            return True
+        group = self.decoding[: self.cfg.decode_batch]
+        n = len(group)
+        # A short group is padded with copies of its first row, so
+        # decode always runs at one shape and compiles once.
+        pad = self.cfg.decode_batch - n
+        toks = np.array([[r.output[-1]] for r, _ in group]  # uep-lint: disable=host-sync
+                        + [[group[0][0].output[-1]]] * pad, np.int32)
+        with tracing.span("engine.stack_caches", n=n):
+            caches = self.stack_caches([c for _, c in group]
+                                       + [group[0][1]] * pad)
+        logits = None
+        for attempt in range(self.cfg.max_retries + 1):
+            try:
+                with tracing.span("engine.decode", n=n):
+                    logits, caches = self.decode_fn(jnp.asarray(toks),
+                                                    caches)
+                break
+            except jax.errors.JaxRuntimeError:
+                raise
+            except RuntimeError:
+                if attempt == self.cfg.max_retries:
+                    # Retire the whole group: a decode step that
+                    # keeps faulting must not wedge the queue.
+                    for r, _ in group:
+                        self._fail(r)
+                    self.decoding = self.decoding[self.cfg.decode_batch:]
+                else:
+                    self.fault_counters["decode_retries"] += 1
+        if logits is None:
+            return False
+        self._advance(self.clock_fn() if self.clock_fn else 0.0)
+        still = []
+        with tracing.span("engine.sample", n=n):
+            logits_np = np.asarray(logits[:, -1])  # uep-lint: disable=host-sync
+            for i, (r, _) in enumerate(group):
+                r.output.append(self._argmax_token(logits_np[i]))
+                if len(r.output) >= r.max_new_tokens:
+                    r.done_at = self.now
+                    self.finished.append(r)
+                else:
+                    still.append(i)
+        with tracing.span("engine.unstack_caches", n=n):
+            new_caches = self.unstack_caches(caches, n)
+        self.decoding = ([(group[i][0], new_caches[i]) for i in still]
+                         + self.decoding[self.cfg.decode_batch:])
+        return True
 
     @staticmethod
     def unstack(caches, n):

@@ -75,11 +75,16 @@ def test_reduced_decode_step(arch):
     caches = init_caches(cfg, B, 16, rcfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, 1), 0,
                               cfg.vocab_size)
-    logits, caches = jax.jit(
+    logits, caches, counters = jax.jit(
         lambda p, c, t: decode_step(p, c, t, cfg, rcfg, PCTX))(params,
                                                                caches, toks)
     assert logits.shape == (B, 1, cfg.vocab_size)
     assert np.isfinite(np.asarray(logits, np.float32)).all()
+    # Each MoE layer routes every token's top-k pairs: held or dropped.
+    routed = [B * cfg.moe.top_k if k.endswith("+moe") else 0
+              for k in layer_kinds(cfg)]
+    np.testing.assert_array_equal(
+        np.asarray(counters.held) + np.asarray(counters.drops), routed)
 
 
 @pytest.mark.parametrize("arch", ASSIGNED_ARCHS)
