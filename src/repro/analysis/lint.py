@@ -29,7 +29,7 @@ with ``# uep-lint: skip-file`` in its first ten lines):
                          rack-major reshapes as in ``two_hop_all_to_all``).
 * ``stage-boundary``  -- the MoE dispatch/permute/distribute engine
                          primitives (``fused_dispatch``, ``fused_bucket``,
-                         ``materialize_replicas``, ...) may only be called
+                         ``materialize_replica_stack``, ...) may only be called
                          from the staged execution layer
                          (``repro.moe.stages``) and the engine modules
                          themselves.  Everything else must go through the
@@ -150,7 +150,7 @@ _FALLBACK_PATH_PARTS = ("repro",)
 _STAGE_PRIMS = frozenset({
     "fused_dispatch", "fused_bucket", "fused_unbucket", "fused_combine",
     "fused_replicated_bucket", "fused_replicated_combine",
-    "two_hop_all_to_all", "materialize_replicas", "materialize_replica_stack",
+    "two_hop_all_to_all", "materialize_replica_stack",
     "dispatch_tokens", "bucket_by_slot", "unbucket", "combine_tokens",
 })
 # moe/ module stems allowed to call them: the stage driver plus the modules

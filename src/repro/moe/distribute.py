@@ -13,10 +13,10 @@ i.e. ``jax.grad`` mechanically derives the replica-gradient reduction onto
 main experts -- the training-equivalence property of S4.2 holds by
 construction rather than by a hand-written mirror kernel.
 
-Chunking over the FFN dimension plays the role of the paper's tile streaming:
-``n_chunks`` bounds the transient buffer (R*N_slot*D*F/n_chunks) and gives
-the XLA latency-hiding scheduler independent transfers to overlap with
-gating/reroute compute.  The per-transfer byte volume equals the paper's:
+Chunking over the packed weight axis plays the role of the paper's tile
+streaming: ``n_chunks`` bounds the transient buffer (R*N_slot*3*D*F/n_chunks)
+and gives the XLA latency-hiding scheduler independent transfers to overlap
+with gating/reroute compute.  The per-transfer byte volume equals the paper's:
 each rank *receives* exactly its N_slot inbound replicas.
 """
 
@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from repro.core.quantize import decode_wire, encode_wire
 
-__all__ = ["replica_selector", "select_local_replicas", "materialize_replicas",
+__all__ = ["replica_selector", "select_local_replicas",
            "materialize_replica_stack"]
 
 
@@ -53,27 +53,52 @@ def replica_selector(x_slots_flat: jax.Array, local_expert_base: jax.Array,
     return onehot * in_range[:, None].astype(jnp.float32)
 
 
+@jax.custom_vjp
+def _take_rows(w: jax.Array, idx: jax.Array) -> jax.Array:
+    """``w[idx]`` for a short (n,) vector of in-range row indices.
+
+    One dynamic slice per row: the TPU compiler lowers a gather of whole
+    expert rows by slicing the entire tensor into tiles first, which
+    rewrites all of ``w`` to read ``n`` rows of it.  The transpose is the
+    gather's, a scatter-add of the row cotangents onto ``w``.
+    """
+    return jnp.concatenate([jax.lax.dynamic_slice_in_dim(w, idx[j], 1)
+                            for j in range(idx.shape[0])])
+
+
+def _take_rows_fwd(w, idx):
+    return _take_rows(w, idx), (w, idx)
+
+
+def _take_rows_bwd(res, ct):
+    w, idx = res
+    return jnp.zeros_like(w).at[idx].add(ct), None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
 def select_local_replicas(w_local: jax.Array, x_slots_flat: jax.Array,
                           local_expert_base: jax.Array) -> jax.Array:
-    """(R*N_slot, D, F) partial replica tensor via masked ``jnp.take``.
+    """(R*N_slot, ...) partial replica tensor via a masked row gather.
 
-    Equals ``einsum('je,edf->jdf', replica_selector(...), w_local)`` but as a
-    gather: slots bound to one of this rank's mains copy that expert's rows,
-    every other slot contributes zeros (so the cross-rank psum still sums to
-    exactly one home contribution per slot).  The transpose under ``jax.grad``
-    is a segment-sum of replica gradients onto mains -- the same reduction
-    the one-hot matmul transposed into.
+    Equals ``einsum('je,edf->jdf', replica_selector(...), w_local)`` but
+    moves only the selected rows: slots bound to one of this rank's mains
+    copy that expert's rows, every other slot contributes zeros (so the
+    cross-rank psum still sums to exactly one home contribution per slot).
+    The transpose under ``jax.grad`` is a segment-sum of replica gradients
+    onto mains -- the same reduction the one-hot matmul transposed into.
     """
     epr = w_local.shape[0]
     local_idx = x_slots_flat - local_expert_base          # (R*N_slot,)
     in_range = (local_idx >= 0) & (local_idx < epr)
-    rows = jnp.take(w_local, jnp.clip(local_idx, 0, epr - 1), axis=0)
+    rows = _take_rows(w_local, jnp.clip(local_idx, 0, epr - 1))
     return jnp.where(in_range[:, None, None], rows,
                      jnp.zeros((), w_local.dtype))
 
 
 def _scatter_replicas(partial: jax.Array, axis_name, racks: int) -> jax.Array:
-    """Reduce-scatter one (R, N_slot, D, Fc) partial onto this rank's slots.
+    """Reduce-scatter one (R, N_slot, ...) partial onto this rank's slots.
 
     Flat EP axis (``axis_name`` a string): a single ``psum_scatter``.
 
@@ -92,73 +117,16 @@ def _scatter_replicas(partial: jax.Array, axis_name, racks: int) -> jax.Array:
     Every slot has exactly one nonzero (home) contribution, so both shapes
     produce bit-identical replica weights.
     """
-    R, n_slot, D, Fc = partial.shape
+    R = partial.shape[0]
     if isinstance(axis_name, (tuple, list)):
         rack_axis, lane_axis = axis_name
-        t = partial.reshape(racks, R // racks, n_slot, D, Fc)
+        t = partial.reshape((racks, R // racks) + partial.shape[1:])
         t = jax.lax.psum_scatter(t, lane_axis, scatter_dimension=1,
-                                 tiled=False)          # (G, n_slot, D, Fc)
+                                 tiled=False)          # (G, n_slot, ...)
         return jax.lax.psum_scatter(t, rack_axis, scatter_dimension=0,
-                                    tiled=False)       # (n_slot, D, Fc)
+                                    tiled=False)       # (n_slot, ...)
     return jax.lax.psum_scatter(partial, axis_name, scatter_dimension=0,
                                 tiled=False)
-
-
-def materialize_replicas(
-    w_local: jax.Array,
-    x_slots: jax.Array,
-    my_rank: jax.Array,
-    axis_name: str | tuple[str, str] | None,
-    *,
-    n_chunks: int = 1,
-    racks: int = 1,
-) -> jax.Array:
-    """Gather this rank's replica weights from their home ranks.
-
-    Args:
-      w_local: (E_local, D, F) this rank's main expert weights.
-      x_slots: (R, N_slot) the plan's slot table (identical on all ranks).
-      my_rank: scalar EP rank index of the caller (rack-major when factored).
-      axis_name: shard_map axis of the EP group -- a single axis name, a
-        ``(rack_axis, lane_axis)`` tuple for two-stage tiered streaming over
-        a factored mesh, or None = single-rank mode (R == 1), where replicas
-        are just local gathers.
-      n_chunks: tile-streaming knob -- chunks of the last (F) dimension.
-      racks: rack count of the factored EP group (ignored for flat axes).
-
-    Returns:
-      (N_slot, D, F) replica weights for this rank's redundant slots; zero
-      for empty slots.
-    """
-    epr, D, F = w_local.shape
-    R, n_slot = x_slots.shape
-    flat = x_slots.reshape(-1)  # (R*n_slot,)
-
-    if axis_name is None:
-        # Single-rank EP group: replicas are local (or empty).
-        rep = select_local_replicas(w_local, flat, jnp.asarray(0, flat.dtype))
-        return rep.reshape(R, n_slot, D, F)[0]
-
-    base = (my_rank * epr).astype(flat.dtype)
-
-    if n_chunks <= 1:
-        partial = select_local_replicas(w_local, flat, base)
-        return _scatter_replicas(partial.reshape(R, n_slot, D, F), axis_name,
-                                 racks)
-    # Tile streaming: chunk the F dimension so the transient send buffer is
-    # (R*n_slot, D, F/n_chunks) and chunks pipeline under the XLA scheduler.
-    chunk = -(-F // n_chunks)
-    outs = []
-    for c in range(n_chunks):
-        lo = c * chunk
-        w_c = jax.lax.dynamic_slice_in_dim(w_local, lo, min(chunk, F - lo), 2)
-        partial = select_local_replicas(w_c, flat, base)
-        outs.append(
-            _scatter_replicas(
-                partial.reshape(R, n_slot, D, w_c.shape[-1]), axis_name, racks
-            )
-        )
-    return jnp.concatenate(outs, axis=-1)
 
 
 def materialize_replica_stack(
@@ -171,46 +139,74 @@ def materialize_replica_stack(
     racks: int = 1,
     wire_dtype: str = "none",
 ) -> tuple[jax.Array, ...]:
-    """One collective schedule for several per-expert weight tensors.
+    """Gather this rank's replica weights from their home ranks.
 
-    Streaming w1/w3/w2 as three independent :func:`materialize_replicas`
-    calls pays three collective launch schedules (and three tile-streaming
-    loops) for traffic that shares one (slot -> home) routing.  This packs
-    every tensor's trailing dims into one (E_local, 1, total) matrix, runs a
-    single transfer, and splits the result back.  ``psum_scatter`` is
-    elementwise over the packed axis, so each returned tensor is
-    bit-identical to its standalone transfer; ``n_chunks`` tiles the packed
-    payload instead of each tensor separately.
+    Select first, then pack: each tensor of ``ws`` gives up only the
+    ``R*N_slot`` rows the slot table asks for (:func:`select_local_replicas`;
+    zero for slots homed on another rank), so no tensor with ``E_local``
+    rows is copied -- the bytes moved are the replicas' own.  The partials
+    are encoded for the wire and packed along their trailing dims into one
+    ``(R, N_slot, total)`` payload, so w1/w3/w2 share ONE collective
+    schedule; ``psum_scatter`` is elementwise over the packed axis, so each
+    returned tensor is bit-identical to its standalone transfer.
 
-    ``wire_dtype`` quantizes the stream (DESIGN.md S12): each tensor is
-    encoded once at the home rank (per-row symmetric int8, fp32 scales
-    packed in-band by :func:`repro.core.quantize.encode_wire`, or a bf16
-    cast) and the encoded bytes ride the same packed reduce-scatter.  The
-    reduction stays exact on encoded payloads because every slot has exactly
-    ONE nonzero (home) contribution and all-zero rows encode to scale 0, so
-    the cross-rank sum reproduces the home encoding bit-for-bit; decode
-    happens once on the receiver.  Replica weights are then a quantized
-    image of their mains (lossy at int8/bf16) while mains stay exact.
+    ``wire_dtype`` quantizes the stream (DESIGN.md S12): each selected row is
+    encoded at the home rank (per-row symmetric int8, fp32 scales packed
+    in-band by :func:`repro.core.quantize.encode_wire`, or a bf16 cast) --
+    the codec is per row along the last axis, so encoding the selected rows
+    equals selecting the encoded rows.  The reduction stays exact on encoded
+    payloads because every slot has exactly ONE nonzero (home) contribution
+    and all-zero rows encode to scale 0, so the cross-rank sum reproduces
+    the home encoding bit-for-bit; decode happens once on the receiver.
+    Replica weights are then a quantized image of their mains (lossy at
+    int8/bf16) while mains stay exact.
 
     Args:
       ws: per-expert weight tensors, each (E_local, ...) with identical
-        leading dim (e.g. ``(w1, w3, w2)``).
+        leading dim (e.g. ``(w1, w3, w2)``): this rank's mains.
+      x_slots: (R, N_slot) the plan's slot table (identical on all ranks).
+      my_rank: scalar EP rank index of the caller (rack-major when factored).
+      axis_name: shard_map axis of the EP group -- a single axis name, a
+        ``(rack_axis, lane_axis)`` tuple for two-stage tiered streaming over
+        a factored mesh, or None = single-rank mode (R == 1), where replicas
+        are just local gathers.
+      n_chunks: tile-streaming knob -- chunks of the packed trailing axis.
+      racks: rack count of the factored EP group (ignored for flat axes).
 
     Returns:
-      A tuple of replica tensors, the i-th shaped ``(N_slot,) + ws[i].shape[1:]``.
+      A tuple of replica tensors, the i-th shaped
+      ``(N_slot,) + ws[i].shape[1:]``; zero for empty slots.
     """
     epr = ws[0].shape[0]
-    enc = [encode_wire(w, wire_dtype) for w in ws]
-    sizes = [math.prod(w.shape[1:]) for w in enc]
-    packed = jnp.concatenate(
-        [w.reshape(epr, 1, -1) for w in enc], axis=-1)    # (E_local, 1, tot)
-    rep = materialize_replicas(packed, x_slots, my_rank, axis_name,
-                               n_chunks=n_chunks, racks=racks)
-    n_slot = rep.shape[0]
+    R, n_slot = x_slots.shape
+    flat = x_slots.reshape(-1)  # (R*n_slot,)
+    base = (jnp.asarray(0, flat.dtype) if axis_name is None
+            else (my_rank * epr).astype(flat.dtype))
+    enc = [encode_wire(select_local_replicas(w, flat, base), wire_dtype)
+           for w in ws]                                  # (R*n_slot, ...)
+    if axis_name is None:
+        # Single-rank EP group (R == 1): the partials are the replicas.
+        return tuple(decode_wire(e, wire_dtype, w.dtype)
+                     for w, e in zip(ws, enc))
+
+    sizes = [math.prod(e.shape[1:]) for e in enc]
+    packed = jnp.concatenate([e.reshape(R, n_slot, -1) for e in enc],
+                             axis=-1)                    # (R, n_slot, tot)
+    if n_chunks <= 1:
+        rep = _scatter_replicas(packed, axis_name, racks)
+    else:
+        # Tile streaming: chunk the packed axis so the transient send buffer
+        # is (R*n_slot, tot/n_chunks) and chunks pipeline under the XLA
+        # scheduler.
+        tot = packed.shape[-1]
+        chunk = -(-tot // n_chunks)
+        rep = jnp.concatenate(
+            [_scatter_replicas(packed[..., lo:lo + chunk], axis_name, racks)
+             for lo in range(0, tot, chunk)], axis=-1)   # (n_slot, tot)
     out = []
     off = 0
     for w, e, sz in zip(ws, enc, sizes):
-        r = rep[:, 0, off:off + sz].reshape((n_slot,) + e.shape[1:])
+        r = rep[:, off:off + sz].reshape((n_slot,) + e.shape[1:])
         out.append(decode_wire(r, wire_dtype, w.dtype))
         off += sz
     return tuple(out)

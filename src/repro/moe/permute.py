@@ -65,6 +65,10 @@ __all__ = [
 
 _I32 = jnp.int32
 
+# Expert FFN output over the physical slots: one (num_slots, cap_slot, D)
+# array, or its consecutive slot ranges (mains, then replicas).
+SlotOut = jax.Array | tuple[jax.Array, ...]
+
 
 class FusedDispatch(NamedTuple):
     """Source-side dispatch state: send buffers + saved permutation inverse."""
@@ -317,10 +321,30 @@ def fused_bucket(
     return xs, valid, meta, drops
 
 
-def fused_unbucket(out: jax.Array, meta: BucketMeta) -> jax.Array:
+def _slot_rows(out: SlotOut, slot: jax.Array, pos: jax.Array) -> jax.Array:
+    """``out[slot, pos]`` of the (num_slots, cap_slot, D) slot outputs.
+
+    ``out`` is the array itself or a tuple of its consecutive slot ranges
+    (the mains' outputs, then the replicas'): each range answers the slots
+    it holds, so the concatenation is never built.  Indices are clamped as
+    a gather on the whole array clamps them.
+    """
+    if not isinstance(out, tuple):
+        return out[slot, pos]
+    rows, base = None, 0
+    for part in out:
+        local = slot - base
+        r = part[jnp.clip(local, 0, part.shape[0] - 1), pos]
+        rows = r if rows is None else jnp.where((local >= 0)[..., None],
+                                                r, rows)
+        base += part.shape[0]
+    return rows
+
+
+def fused_unbucket(out: SlotOut, meta: BucketMeta) -> jax.Array:
     """Inverse of :func:`fused_bucket`: a pure gather back to (R, cap_pair)."""
-    ret = out[meta.slot, meta.pos]                        # (R, cap_pair, D)
-    return jnp.where(meta.valid[:, :, None], ret, jnp.zeros((), out.dtype))
+    ret = _slot_rows(out, meta.slot, meta.pos)            # (R, cap_pair, D)
+    return jnp.where(meta.valid[:, :, None], ret, jnp.zeros((), ret.dtype))
 
 
 def _tokenwise_sum(vals: jax.Array) -> jax.Array:
@@ -420,15 +444,15 @@ def fused_replicated_bucket(
 
 
 def fused_replicated_combine(
-    out: jax.Array,
+    out: SlotOut,
     bucket: ReplicatedBucket,
     weights: jax.Array,
 ) -> jax.Array:
     """Per-item gather from the slot buffers + token-major weighted sum."""
     T, k = weights.shape
-    D = out.shape[-1]
     safe_slot = jnp.where(bucket.item_ok, bucket.item_slot, 0)
     safe_pos = jnp.where(bucket.item_ok, bucket.item_pos, 0)
     flat_w = weights.reshape(-1) * bucket.item_ok.astype(weights.dtype)
-    vals = out[safe_slot, safe_pos] * flat_w[:, None].astype(out.dtype)
-    return _tokenwise_sum(vals.reshape(T, k, D))
+    rows = _slot_rows(out, safe_slot, safe_pos)
+    vals = rows * flat_w[:, None].astype(rows.dtype)
+    return _tokenwise_sum(vals.reshape(T, k, rows.shape[-1]))

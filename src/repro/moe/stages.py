@@ -8,9 +8,9 @@ module decomposes it into six explicit stages (DESIGN.md S11):
 
   GateStage        gate + exact load gather            -> GateState
   PlanStage        balancer solve + slot table         -> PlanState
-  DistributeStage  stacked replica weight streaming    -> DistributeState
+  DistributeStage  replica slots' weight streaming     -> DistributeState
   DispatchStage    reroute + pack + (two-hop) a2a      -> DispatchState
-  ComputeStage     grouped FFN over physical slots     -> (slots, cap, D)
+  ComputeStage     grouped FFN over physical slots     -> slot outputs
   CombineStage     inverse wire + weighted reduce      -> (T_chunk, D)
 
 and rebuilds the layer as :func:`run_staged_moe`, a thin driver that
@@ -160,11 +160,18 @@ class PlanState(NamedTuple):
 
 
 class DistributeState(NamedTuple):
-    """DistributeStage output: main + replica weights per physical slot."""
+    """DistributeStage output: the weights of every physical slot.
 
-    w1_all: jax.Array    # (num_slots, D, F)
-    w3_all: jax.Array    # (num_slots, D, F)
-    w2_all: jax.Array    # (num_slots, F, D)
+    Slot ``j < E_local`` reads main ``j`` -- the parameter arrays themselves,
+    never copied -- and slot ``E_local + i`` reads replica ``i``.
+    """
+
+    w1: jax.Array        # (E_local, D, F) mains
+    w3: jax.Array        # (E_local, D, F)
+    w2: jax.Array        # (E_local, F, D)
+    w1r: jax.Array       # (N_slot, D, F) streamed replicas
+    w3r: jax.Array       # (N_slot, D, F)
+    w2r: jax.Array       # (N_slot, F, D)
 
 
 class DispatchState(NamedTuple):
@@ -519,16 +526,18 @@ def plan_stage(ctx: StageCtx, gs: GateState, *,
 
 def distribute_stage(ctx: StageCtx, params, gs: GateState,
                      ps: PlanState) -> DistributeState:
-    """Stream replica weights: ONE stacked transfer for w1/w3/w2."""
+    """Stream the replica slots' weights: ONE packed transfer for w1/w3/w2.
+
+    Moves ``N_slot`` experts' rows per rank (``R*N_slot`` rows on the EP
+    wire); the mains ride along by reference.
+    """
     cfg = ctx.cfg
     w1r, w3r, w2r = materialize_replica_stack(
         (params.w1, params.w3, params.w2), ps.plan.x, gs.my, ctx.axis_name,
         n_chunks=cfg.distribute_chunks, racks=cfg.racks,
         wire_dtype=cfg.wire_dtype)
-    return DistributeState(
-        w1_all=jnp.concatenate([params.w1, w1r], axis=0),
-        w3_all=jnp.concatenate([params.w3, w3r], axis=0),
-        w2_all=jnp.concatenate([params.w2, w2r], axis=0))
+    return DistributeState(w1=params.w1, w3=params.w3, w2=params.w2,
+                           w1r=w1r, w3r=w3r, w2r=w2r)
 
 
 def _distribute_with_ladder(
@@ -558,10 +567,8 @@ def _distribute_with_ladder(
                        slot_of_all=physical_slot_of(cfg.layout, plan.x))
     dist = distribute_stage(ctx, params, gs, ps)
     if res.injector is not None:
-        n_main = cfg.layout.experts_per_rank
-        w1r = res.injector.corrupt_replicas(dist.w1_all[n_main:], res.layer)
         dist = dist._replace(
-            w1_all=jnp.concatenate([dist.w1_all[:n_main], w1r], axis=0))
+            w1r=res.injector.corrupt_replicas(dist.w1r, res.layer))
     return ps, dist
 
 
@@ -657,41 +664,61 @@ def dispatch_stage(ctx: StageCtx, x_chunk: jax.Array,
 
 
 def compute_stage(ctx: StageCtx, ds: DispatchState,
-                  dist: DistributeState) -> jax.Array:
-    """Grouped FFN over this rank's physical slots for one chunk."""
-    return grouped_ffn(ds.xs, ds.valid, dist.w1_all, dist.w3_all,
-                       dist.w2_all, use_kernel=ctx.cfg.use_kernel,
-                       ffn_dtype=ctx.cfg.ffn_dtype, xs_scale=ds.xs_scale)
+                  dist: DistributeState) -> tuple[jax.Array, ...]:
+    """Grouped FFN over this rank's physical slots for one chunk.
+
+    The main slots run against the mains and the replica slots against the
+    streamed replicas: the same per-slot SwiGLU, with no (num_slots, D, F)
+    weight stack built to feed it.  Returns the slot outputs as the two
+    consecutive slot ranges ``(mains, replicas)``, each
+    ``(slots, cap_slot, D)``; CombineStage reads them without concatenating.
+    """
+    cfg = ctx.cfg
+    n_main = dist.w1.shape[0]
+
+    def ffn(lo, hi, w1, w3, w2):
+        return grouped_ffn(
+            ds.xs[lo:hi], ds.valid[lo:hi], w1, w3, w2,
+            use_kernel=cfg.use_kernel, ffn_dtype=cfg.ffn_dtype,
+            xs_scale=None if ds.xs_scale is None else ds.xs_scale[lo:hi])
+
+    return (ffn(0, n_main, dist.w1, dist.w3, dist.w2),
+            ffn(n_main, None, dist.w1r, dist.w3r, dist.w2r))
 
 
-def combine_stage(ctx: StageCtx, ds: DispatchState, out: jax.Array,
+def combine_stage(ctx: StageCtx, ds: DispatchState,
+                  outs: tuple[jax.Array, ...],
                   weights: jax.Array) -> jax.Array:
     """Route FFN outputs back and reduce each token's k contributions.
 
-    ``weights`` is the (T_chunk, k) gate-weight slice of this chunk; the
-    return is the chunk's (T_chunk, D) combined output (pre-psum for the
-    replicated mode -- the driver merges ranks once over the whole batch).
+    ``outs`` is ComputeStage's slot outputs; ``weights`` is the (T_chunk, k)
+    gate-weight slice of this chunk; the return is the chunk's (T_chunk, D)
+    combined output (pre-psum for the replicated mode -- run_staged_moe merges
+    ranks once over the whole batch).  The fused engine gathers from the
+    slot ranges as they are; the reference oracle scatters from their
+    concatenation.
     """
     cfg = ctx.cfg
+    if cfg.dispatch_impl == "fused":
+        if cfg.dispatch_mode == "replicated":
+            return fused_replicated_combine(outs, ds.inverse, weights)
+        # The return wire carries the same codec as the forward wire: FFN
+        # outputs are encoded per-row before the reverse exchange and decoded
+        # at the source rank, right before the weighted reduce.
+        disp, meta = ds.inverse
+        ret = _exchange(ctx, encode_wire(fused_unbucket(outs, meta),
+                                         cfg.wire_dtype), reverse=True)
+        return fused_combine(decode_wire(ret, cfg.wire_dtype, outs[0].dtype),
+                             disp, weights)
+    out = jnp.concatenate(outs, axis=0)
     D = out.shape[-1]
     if cfg.dispatch_mode == "replicated":
-        if cfg.dispatch_impl == "fused":
-            return fused_replicated_combine(out, ds.inverse, weights)
         Tc, k = weights.shape
         ret = unbucket(out, ds.valid, ds.inverse, (1, Tc * k, D))
         flat_w = weights.reshape(-1)
         items_t = jnp.repeat(jnp.arange(Tc, dtype=_I32), k)
         vals = ret[0] * flat_w[:, None].astype(ret.dtype)
         return jnp.zeros((Tc, D), ret.dtype).at[items_t].add(vals)
-    if cfg.dispatch_impl == "fused":
-        # The return wire carries the same codec as the forward wire: FFN
-        # outputs are encoded per-row before the reverse exchange and decoded
-        # at the source rank, right before the weighted reduce.
-        disp, meta = ds.inverse
-        ret = _exchange(ctx, encode_wire(fused_unbucket(out, meta),
-                                         cfg.wire_dtype), reverse=True)
-        return fused_combine(decode_wire(ret, cfg.wire_dtype, out.dtype),
-                             disp, weights)
     disp, back_idx = ds.inverse
     ret = unbucket(out, ds.valid, back_idx, (cfg.ep_size, cfg.cap_pair, D))
     if ctx.axis_name is not None:
@@ -829,10 +856,10 @@ def run_staged_moe(
             d_cur = d_cur._replace(xs=xs, valid=valid)
             dropped_payload = dropped_payload + n_bad
         with jax.named_scope("moe.ffn"):
-            out = compute_stage(ctx, d_cur, dist)
+            outs = compute_stage(ctx, d_cur, dist)
         s, ln = bounds[i]
         with jax.named_scope("moe.combine"):
-            y_chunk = combine_stage(ctx, d_cur, out,
+            y_chunk = combine_stage(ctx, d_cur, outs,
                                     gs.gate_out.weights[s:s + ln])
             if screening:
                 y_chunk, n_bad = _screen_rows(y_chunk)

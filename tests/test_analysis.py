@@ -491,9 +491,9 @@ class TestLint:
         assert _rules(src, "src/repro/core/stages.py") == {"stage-boundary"}
 
     def test_stage_boundary_suppression(self):
-        src = ("from repro.moe.distribute import materialize_replicas\n"
+        src = ("from repro.moe.distribute import materialize_replica_stack\n"
                "def f(w, xs, r):\n"
-               "    return materialize_replicas(w, xs, r, 'model')"
+               "    return materialize_replica_stack(w, xs, r, 'model')"
                "  # uep-lint: disable=stage-boundary\n")
         assert _rules(src) == set()
 

@@ -10,8 +10,9 @@ instance and per-chunk traffic is a subset of the unchunked traffic.
 
 Covered here: config validation, single-rank bit-identity for all three
 dispatch modes x 2/4 chunks, gradients, drop accounting under tight caps,
-the chunking helpers, and real-collective identity on flat 8-rank and
-factored (2 racks x 4 lanes) meshes.
+replica slots that carry tokens (4 ranks emulated by ``vmap``), the
+chunking helpers, and real-collective identity on flat 8-rank and factored
+(2 racks x 4 lanes) meshes.
 """
 
 import jax
@@ -145,6 +146,102 @@ def test_overlap_stats_match_unchunked_at_zero_drop(setup):
     assert int(s0.post_max) == int(s1.post_max)
     # Per-chunk slot occupancy can only be <= the unchunked occupancy.
     assert int(s1.max_slot_load) <= int(s0.max_slot_load)
+
+
+# ------------------------------------- replica slots carry the tokens ----
+
+_EP = 4
+
+
+def _ep_cfg(mode, **kw):
+    return MoEConfig(
+        gating=GatingConfig(num_experts=E, top_k=K),
+        balancer=BalancerConfig(mode=mode, n_slot=2),
+        d_model=64, d_ff=64, ep_size=_EP, cap_pair=T * K,
+        cap_slot=T * K * _EP, **kw)
+
+
+@pytest.fixture(scope="module")
+def hot_ep():
+    """(router, x, (w1, w3, w2)) for 4 ranks, experts 3 and 5 hot."""
+    cfg = _ep_cfg("ultraep")
+    D, F, epr = cfg.d_model, cfg.d_ff, E // _EP
+    pk = jax.random.split(jax.random.PRNGKey(0), 5)
+    router = (jax.random.normal(pk[0], (D, E)) * D ** -0.5
+              ).at[:, 3].add(0.3).at[:, 5].add(0.2)
+    x = jax.random.normal(pk[1], (_EP, T, D))
+    ws = (jax.random.normal(pk[2], (_EP, epr, D, F)) * D ** -0.5,
+          jax.random.normal(pk[3], (_EP, epr, D, F)) * D ** -0.5,
+          jax.random.normal(pk[4], (_EP, epr, F, D)) * F ** -0.5)
+    return router, x, ws
+
+
+def _run_ep(cfg, router, x, ws):
+    """The staged layer on ``_EP`` ranks, emulated by ``vmap`` over a named
+    axis: every collective of the layer runs over the batch of ranks."""
+    from repro.moe.layer import MoEParams
+
+    def body(x, w1, w3, w2):
+        y, _, st = moe_layer_local(x, MoEParams(router, w1, w3, w2), cfg,
+                                   axis_name="model")
+        return y, st.drops_dispatch + st.drops_slot
+
+    return jax.jit(jax.vmap(body, axis_name="model"))(x, *ws)
+
+
+@pytest.mark.parametrize("variant", ["einsum", "kernel", "int8",
+                                     "replicated"])
+def test_replica_slots_match_replica_free_path(variant, hot_ep, request):
+    """A hot expert fills both replica slots of a rank with tokens; the
+    layer's output equals the replica-free path's (every token on its home
+    main) bit for bit at zero-drop capacities, so the replica half of
+    ComputeStage reads exactly the mains' weights.  The w8a8 path also
+    stays within its tolerance of the fp replica-free output.  The
+    replicated (decode) dispatch gives every rank all the tokens and
+    merges ranks by a psum, which reassociates: float32 rounding there."""
+    from repro.moe import stages
+
+    router, x, ws = hot_ep
+    kw = {"kernel": {"use_kernel": True}, "int8": {"ffn_dtype": "int8"},
+          "replicated": {"dispatch_mode": "replicated"}, "einsum": {}
+          }[variant]
+    if variant == "replicated":
+        x = jnp.broadcast_to(x.reshape(1, -1, x.shape[-1]),
+                             (_EP, _EP * T, x.shape[-1]))
+    if variant == "kernel":
+        request.getfixturevalue("tpu_interpret")
+    cfg = _ep_cfg("ultraep", **kw)
+    ctx = stages.make_stage_ctx(cfg, "model")
+
+    def replica_loads(x):
+        gs = stages.gate_stage(ctx, x, router)
+        ps = stages.plan_stage(ctx, gs)
+        ds = stages.dispatch_stage(ctx, x, gs.gate_out.expert_ids, gs, ps)
+        return ds.valid[cfg.layout.experts_per_rank:].sum(axis=-1)
+
+    loads = np.asarray(
+        jax.jit(jax.vmap(replica_loads, axis_name="model"))(x))
+    assert (loads > 0).all(axis=1).any(), loads
+    if variant == "kernel":
+        jaxpr = jax.make_jaxpr(lambda *a: _run_ep(cfg, router, *a))(x, ws)
+        assert "pallas_call" in str(jaxpr)
+
+    y, drops = _run_ep(cfg, router, x, ws)
+    y0, drops0 = _run_ep(_ep_cfg("none", **kw), router, x, ws)
+    assert not np.asarray(drops).any() and not np.asarray(drops0).any()
+    y, y0 = np.asarray(y), np.asarray(y0)
+    if variant == "replicated":
+        # The rank merge is a psum of per-rank partial sums: a token whose
+        # contributions the plan moves to a replica's rank sums them in
+        # another order, so the outputs agree to float32 rounding.
+        np.testing.assert_allclose(y, y0, rtol=1e-6,
+                                   atol=1e-6 * np.abs(y0).max())
+    else:
+        assert np.array_equal(y, y0), (variant, np.abs(y - y0).max())
+    if variant == "int8":
+        yf = np.asarray(_run_ep(_ep_cfg("none"), router, x, ws)[0])
+        scale = np.abs(yf).max()
+        assert np.allclose(y, yf, rtol=1e-2, atol=3e-2 * scale)
 
 
 # --------------------------------------------------- chunking helpers ---
