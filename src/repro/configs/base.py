@@ -31,6 +31,30 @@ class MoEArch:
     layer_period: int = 1           # MoE every k-th layer (jamba: 2)
     first_dense_layers: int = 0     # leading dense-FFN layers (deepseek: 3)
     n_slot: int = 2                 # redundant slots per rank (Table 3)
+    # Expert share: the block of the router's experts this deployment's
+    # chip holds, ``held_experts`` of them from ``first_expert`` (0 = all).
+    # The router keeps its width; pairs routed to experts held elsewhere
+    # are not computed here (moe/gating.py).
+    held_experts: int = 0
+    first_expert: int = 0
+
+    def __post_init__(self):
+        if not (0 <= self.first_expert and 0 <= self.held_experts
+                and self.first_expert + self.held <= self.num_experts):
+            raise ValueError(
+                f"held block [{self.first_expert}, {self.first_expert} + "
+                f"{self.held}) lies outside the router's "
+                f"{self.num_experts} experts")
+
+    @property
+    def held(self) -> int:
+        """Experts this chip holds of each MoE layer."""
+        return self.held_experts or self.num_experts
+
+    @property
+    def holds_share(self) -> bool:
+        """True when the chip holds only a block of the router's experts."""
+        return self.held < self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)

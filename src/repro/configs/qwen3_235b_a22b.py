@@ -1,6 +1,8 @@
-"""qwen3-235b-a22b [paper model]: 94L d_model=4096 64H (GQA kv=4) 128 experts
-top-8, expert d_ff=1536, vocab=151936.  Paper Table 3 evaluation model.
-[arXiv:2505.09388; hf]
+"""qwen3-235b-a22b [paper model]: 94L d_model=4096 64H (GQA kv=4, head 128,
+q/k RMS norm) every layer MoE: 128 experts top-8, softmax over all 128 with
+the top-8 weights renormalised, expert d_ff=1536, no shared expert;
+vocab=151936 untied, RoPE theta 1e6.  Paper Table 3 evaluation model.
+[hf Qwen/Qwen3-235B-A22B config.json; arXiv:2505.09388]
 """
 from repro.configs.base import ModelConfig, MoEArch, register
 
@@ -17,7 +19,10 @@ def qwen3_235b_a22b() -> ModelConfig:
         num_kv_heads=4,
         head_dim=128,
         qk_norm=True,
-        moe=MoEArch(num_experts=128, top_k=8, d_ff=1536, n_slot=2),
+        rope_theta=1e6,
+        moe=MoEArch(num_experts=128, top_k=8, d_ff=1536, score_fn="softmax",
+                    norm_topk_prob=True, n_slot=2),
         shape_skips=("long_500k",),
-        source="arXiv:2505.09388 (paper Table 3)",
+        source="https://huggingface.co/Qwen/Qwen3-235B-A22B/blob/main/"
+               "config.json (arXiv:2505.09388)",
     )

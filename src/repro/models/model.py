@@ -36,10 +36,14 @@ class LMParams(NamedTuple):
 
 class MoECounters(NamedTuple):
     """Routed (token, expert) pairs of one step, per layer ((num_layers,)
-    int32 each; zero on layers without experts)."""
+    int32 each; zero on layers without experts).  ``held + drops +
+    absent`` is the top-k pairs of every token the step routed (a prefill
+    chunk's right-padding is routed too)."""
 
     held: jax.Array      # pairs the layer's expert slots held
     drops: jax.Array     # pairs dropped at pair or slot capacity
+    absent: jax.Array    # pairs routed to experts held on other chips
+                         #   (an expert share; a constant 0 otherwise)
 
 
 def _stack_blocks(blocks: list[BlockParams]) -> BlockParams:
@@ -239,7 +243,8 @@ def _cached_step(params: LMParams, caches, tokens: jax.Array,
     with jax.named_scope("embed"):
         x = embed(tokens, params.embedding)
     segs = segments_for(cfg, rcfg)
-    new_caches, held, drops_all = [], [], []
+    share = cfg.moe is not None and cfg.moe.holds_share
+    new_caches, held, drops_all, absent = [], [], [], []
     for seg, sp, cache in zip(segs, params.segments, caches):
         bias_seg = None
         if router_bias is not None:
@@ -248,10 +253,20 @@ def _cached_step(params: LMParams, caches, tokens: jax.Array,
             x, seg, sp, cfg, rcfg, pctx, caches=cache,
             router_bias=bias_seg, decode=decode, valid_len=valid_len)
         new_caches.append(nc)
-        held.append(counts.sum(axis=-1, dtype=jnp.int32) - drops)
+        routed = counts.sum(axis=-1, dtype=jnp.int32)
+        if share:
+            # counts span the router's width; the layer holds one block.
+            lo = cfg.moe.first_expert
+            here = counts[:, lo:lo + cfg.moe.held].sum(axis=-1,
+                                                       dtype=jnp.int32)
+            absent.append(routed - here)
+            routed = here
+        held.append(routed - drops)
         drops_all.append(drops)
-    counters = MoECounters(held=jnp.concatenate(held),
-                           drops=jnp.concatenate(drops_all))
+    counters = MoECounters(
+        held=jnp.concatenate(held), drops=jnp.concatenate(drops_all),
+        absent=(jnp.concatenate(absent) if share
+                else jnp.zeros((cfg.num_layers,), jnp.int32)))
     return _head(params, x), tuple(new_caches), counters
 
 

@@ -216,9 +216,10 @@ def moe_config(cfg: ModelConfig, rcfg: RuntimeConfig, pctx: ParallelCtx,
         ideal=ideal or rcfg.balancer.mode == "ideal",
         rack_limit=rack_limit,
         num_racks=pctx.racks if rack_limit else 1,
+        held_experts=m.held_experts, first_expert=m.first_expert,
     )
     bal = dataclasses.replace(rcfg.balancer, n_slot=m.n_slot)
-    slots_per_rank = m.num_experts // ep + m.n_slot
+    slots_per_rank = m.held // ep + m.n_slot
     # Factored mesh: size pair buffers with the per-rack aggregate bound --
     # the rack-local reroute tier concentrates a source's traffic in-rack,
     # so the flat ~items/ep_size expectation under-provisions (silent drops).
@@ -362,8 +363,9 @@ def init_block(key: jax.Array, cfg: ModelConfig, kind: str,
             jax.random.normal(k3, (F, D), dtype) * F ** -0.5,
         )
     elif ffn_kind == "moe":
-        # Parameters are GLOBAL (all E experts); the shard_map in_specs
-        # split the expert dim over the EP axis at execution time.  The
+        # Parameters are GLOBAL (all E experts the chip's EP group holds:
+        # the router's width, or an expert share's block); the shard_map
+        # in_specs split the expert dim over the EP axis at execution time.  The
         # single-group init view must also collapse the rack factoring
         # (racks must divide ep_size).
         mcfg = moe_config(cfg, rcfg, pctx, tokens_per_rank=8)  # caps unused

@@ -14,7 +14,10 @@ Covers the router families of the assigned architectures:
     routing, DESIGN.md S14): each token's top-k is restricted to its
     ``rack_limit`` highest-scoring racks, bounding the number of racks a
     token's payload must reach -- and hence the inter-rack volume of the
-    two-hop wire -- *at the source* instead of after the fact.
+    two-hop wire -- *at the source* instead of after the fact;
+  * an **expert share**: a layer that holds one block of the router's
+    experts routes over all of them and keeps only the pairs whose expert
+    it holds (the chip's share of an expert-parallel deployment).
 
 The router runs in fp32 regardless of activation dtype (routing decisions
 are precision-sensitive).
@@ -56,8 +59,20 @@ class GatingConfig:
     # Rack group score = sum of the top rack_group_topk expert scores inside
     # each rack (DeepSeek-V3 uses 2); clamped to the experts per rack.
     rack_group_topk: int = 2
+    # Expert share: this layer holds ``held_experts`` of the router's
+    # experts from ``first_expert`` (0 = all of them).  The router keeps
+    # its width and top-k; :func:`gate` maps the chosen ids into the block
+    # and marks the pairs routed to experts held elsewhere as not routed
+    # here (id -1, weight 0).
+    held_experts: int = 0
+    first_expert: int = 0
 
     def __post_init__(self):
+        if not (0 <= self.first_expert and 0 <= self.held_experts
+                and self.first_expert + self.held <= self.num_experts):
+            raise ValueError(
+                f"held block [{self.first_expert}, {self.first_expert} + "
+                f"{self.held}) lies outside num_experts={self.num_experts}")
         if self.num_racks < 1:
             raise ValueError(f"num_racks={self.num_racks} must be >= 1")
         if not 0 <= self.rack_limit <= self.num_racks:
@@ -79,6 +94,16 @@ class GatingConfig:
                 f"rack_group_topk={self.rack_group_topk} must be >= 1")
 
     @property
+    def held(self) -> int:
+        """Experts the layer holds (the router's width unless a share)."""
+        return self.held_experts or self.num_experts
+
+    @property
+    def holds_share(self) -> bool:
+        """True when the layer holds only a block of the router's experts."""
+        return self.held < self.num_experts
+
+    @property
     def rack_limited(self) -> bool:
         """True when the rack-group mask path is active (may be vacuous)."""
         return self.rack_limit > 0 and self.num_racks > 1
@@ -90,9 +115,13 @@ class GatingConfig:
 
 
 class GateOut(NamedTuple):
-    expert_ids: jax.Array     # (T, k) int32 selected logical experts
-    weights: jax.Array        # (T, k) combine weights (activation dtype)
+    expert_ids: jax.Array     # (T, k) int32 selected logical experts; with
+                              #   a share, ids within the held block and -1
+                              #   for pairs routed to experts held elsewhere
+    weights: jax.Array        # (T, k) combine weights (activation dtype);
+                              #   0 where the id is -1
     counts: jax.Array         # (E,) int32 realized per-expert token load
+                              #   over the router's whole width
     aux_loss: jax.Array       # () scalar (0 when disabled)
     scores: jax.Array         # (T, E) router probabilities (fp32)
 
@@ -244,6 +273,13 @@ def gate(
     aux = jnp.zeros((), jnp.float32)
     if cfg.aux_loss_weight > 0.0:
         aux = cfg.aux_loss_weight * gshard_aux_loss(scores, expert_ids, E)
+    if cfg.holds_share:
+        # Top-k and renormalisation ran over the whole router; a pair whose
+        # expert lies outside the held block is not routed here.
+        local = expert_ids - cfg.first_expert
+        here = (local >= 0) & (local < cfg.held)
+        expert_ids = jnp.where(here, local, -1)
+        sel = jnp.where(here, sel, 0)
     return GateOut(expert_ids, sel.astype(x.dtype), counts, aux, scores)
 
 

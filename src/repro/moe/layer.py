@@ -112,6 +112,11 @@ class MoEConfig:
             raise ValueError(
                 "wire_dtype != 'none' requires dispatch_impl='fused' (the "
                 "reference scatter path is the uncompressed oracle)")
+        if self.gating.holds_share and (self.dispatch_impl != "fused"
+                                        or self.racks != 1):
+            raise ValueError(
+                "an expert share runs flat EP on the fused engine "
+                "(dispatch_impl='fused', racks=1)")
 
     @property
     def ranks_per_rack(self) -> int:
@@ -124,12 +129,13 @@ class MoEConfig:
 
     @property
     def layout(self) -> ExpertLayout:
-        return ExpertLayout(self.gating.num_experts, self.ep_size,
+        return ExpertLayout(self.gating.held, self.ep_size,
                             self.balancer.n_slot)
 
 
 class MoEParams(NamedTuple):
-    router: jax.Array        # (D, E) fp32 router projection
+    router: jax.Array        # (D, E) fp32 router projection, E the router's
+                             #   width (a share holds fewer experts)
     w1: jax.Array            # (E_local, D, F) gate proj (per-rank shard)
     w3: jax.Array            # (E_local, D, F) up proj
     w2: jax.Array            # (E_local, F, D) down proj
@@ -171,9 +177,10 @@ def default_capacities(tokens_per_rank: int, top_k: int, ep_size: int,
 
 def init_moe_params(key: jax.Array, cfg: MoEConfig,
                     dtype=jnp.float32) -> MoEParams:
-    """Per-rank parameter shard (E_local experts)."""
+    """Per-rank parameter shard (E_local experts of the held block; the
+    router spans all of the router's experts)."""
     E = cfg.gating.num_experts
-    epr = E // cfg.ep_size
+    epr = cfg.layout.experts_per_rank
     D, F = cfg.d_model, cfg.d_ff
     ks = jax.random.split(key, 7)
     scale_in = D ** -0.5

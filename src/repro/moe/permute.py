@@ -174,6 +174,7 @@ def fused_dispatch(
     num_slots: int,
     cap_pair: int,
     occ_offset: jax.Array | None = None,
+    routed: jax.Array | None = None,
 ) -> FusedDispatch:
     """Single-sort dispatch: pack the key, sort once, gather everything.
 
@@ -191,6 +192,9 @@ def fused_dispatch(
         chunks sharing one plan; continuing the occurrence index across
         chunks makes every item hit the exact same instance as the unchunked
         dispatch, so the shared quota table stays exactly honoured.
+      routed: optional (T, k) bool, False for a pair an expert share does not
+        route here (its id is -1): it is never sent, kept or counted as a
+        drop.
     """
     T, k = expert_ids.shape
     E, R = cum_q_row.shape
@@ -206,6 +210,10 @@ def fused_dispatch(
     dst = token_targets(e, cumq=cum_q_row, occ=occ)
     slot = dst_slot_of[dst, e]                                   # (N,)
     slot = jnp.where(slot >= 0, slot, num_slots).astype(_I32)    # sentinel
+    if routed is not None:
+        # Past every rank group: the sort puts unrouted pairs last.
+        routed = routed.reshape(-1)
+        dst = jnp.where(routed, dst, R)
 
     # --- THE sort: packed (dst, slot) key, one stable pass -----------------
     key = dst * S1 + slot
@@ -219,7 +227,11 @@ def fused_dispatch(
     # Inverse path = argsort of the permutation: a unique-index scatter.
     item_pos = jnp.zeros((n,), _I32).at[perm].set(pos_sorted)
     kept = item_pos < cap_pair
-    drops = jnp.sum(~kept).astype(_I32)
+    if routed is None:
+        drops = jnp.sum(~kept).astype(_I32)
+    else:
+        kept = kept & routed
+        drops = jnp.sum(routed & ~kept).astype(_I32)
 
     # --- send buffers: pure gathers from the saved permutation -------------
     col = jnp.arange(cap_pair, dtype=_I32)
@@ -391,6 +403,7 @@ def fused_replicated_bucket(
     num_slots: int,
     cap_slot: int,
     occ_offset: jax.Array | None = None,
+    routed: jax.Array | None = None,
 ) -> ReplicatedBucket:
     """Replicated-mode bucketing: one sort over this rank's owned share.
 
@@ -408,6 +421,8 @@ def fused_replicated_bucket(
       occ_offset: optional (E,) per-expert occurrence offset continuing the
         global occurrence index across overlap chunks (see
         :func:`fused_dispatch`), so chunked ownership equals unchunked.
+      routed: optional (T, k) bool as in :func:`fused_dispatch`: a pair an
+        expert share does not route here is no rank's, so never a drop.
     """
     T, k = expert_ids.shape
     E = cum_u.shape[0]
@@ -418,6 +433,8 @@ def fused_replicated_bucket(
         occ = occ + occ_offset[e]
     owner = token_targets(e, cumq=cum_u, occ=occ)
     mine = owner == my_rank
+    if routed is not None:
+        mine = mine & routed.reshape(-1)
     slot = slot_of[e]
     hosted = slot >= 0
     key = jnp.where(mine & hosted, slot, num_slots).astype(_I32)

@@ -442,27 +442,32 @@ def gate_stage(ctx: StageCtx, x: jax.Array, router: jax.Array,
     cfg = ctx.cfg
     R = cfg.ep_size
     gate_out: GateOut = gate(x, router, cfg.gating, bias=router_bias)
+    counts = gate_out.counts
+    if cfg.gating.holds_share:
+        # The plan balances the held block's load only.
+        lo = cfg.gating.first_expert
+        counts = counts[lo:lo + cfg.gating.held]
     if cfg.dispatch_mode == "replicated":
         # Tokens are identical on every EP rank, so counts are already the
         # EP-group totals -- no collective needed.  Attribute the load to the
         # experts' home ranks (source locality is vacuous here).
         home = cfg.layout.home()
         lam = (jax.nn.one_hot(home, R, dtype=_I32)
-               * gate_out.counts[:, None]).T                        # (R, E)
+               * counts[:, None]).T                                 # (R, E)
         my = _my_rank(ctx)
     elif ctx.axis_name is not None:
         if ctx.factored:
             # Two-step gather mirrors the wire: lanes first, then racks,
             # yielding rack-major (= global rank order) load rows.
-            lam = jax.lax.all_gather(gate_out.counts, ctx.lane_axis)
+            lam = jax.lax.all_gather(counts, ctx.lane_axis)
             lam = jax.lax.all_gather(lam, ctx.rack_axis).reshape(R, -1)
         else:
-            lam = jax.lax.all_gather(gate_out.counts, ctx.axis_name)
+            lam = jax.lax.all_gather(counts, ctx.axis_name)
         my = _my_rank(ctx)
     else:
         if R != 1:
             raise ValueError("axis_name=None requires ep_size == 1")
-        lam = gate_out.counts[None]
+        lam = counts[None]
         my = jnp.asarray(0, _I32)
     gate_tiers = None
     if cfg.rack_size is not None and cfg.dispatch_mode != "replicated":
@@ -590,6 +595,8 @@ def dispatch_stage(ctx: StageCtx, x_chunk: jax.Array,
     layout = cfg.layout
     num_slots = layout.experts_per_rank + layout.n_slot
     zero = jnp.zeros((), _I32)
+    # A share's pairs routed to experts held elsewhere (id -1) take no slot.
+    routed = expert_ids >= 0 if cfg.gating.holds_share else None
 
     if cfg.dispatch_mode == "replicated":
         # Tokens identical on every EP rank (decode / exact-reference path):
@@ -600,7 +607,7 @@ def dispatch_stage(ctx: StageCtx, x_chunk: jax.Array,
             rb = fused_replicated_bucket(
                 x_chunk, expert_ids, ps.plan.cum_u, gs.my, slot_of,
                 num_slots=num_slots, cap_slot=cfg.cap_slot,
-                occ_offset=occ_offset,
+                occ_offset=occ_offset, routed=routed,
             )
             return DispatchState(xs=rb.xs, valid=rb.valid, inverse=rb,
                                  drops_dispatch=zero, drops_slot=rb.drops)
@@ -629,6 +636,7 @@ def dispatch_stage(ctx: StageCtx, x_chunk: jax.Array,
         disp = fused_dispatch(
             x_chunk, expert_ids, ps.plan.cum_q[gs.my], ps.slot_of_all,
             num_slots=num_slots, cap_pair=cfg.cap_pair, occ_offset=occ_offset,
+            routed=routed,
         )
         recv_x = _exchange(ctx, encode_wire(disp.send_x, cfg.wire_dtype))
         recv_c = _exchange(ctx, disp.send_counts)
@@ -825,7 +833,7 @@ def run_staged_moe(
     bounds = chunk_bounds(T, n_chunks=C)
     with jax.named_scope("moe.dispatch"):
         offsets = (chunk_occ_offsets(gs.gate_out.expert_ids, C,
-                                     cfg.gating.num_experts)
+                                     cfg.layout.num_experts)
                    if C > 1 else None)
     screening = res is not None and res.cfg.screen_payloads
 

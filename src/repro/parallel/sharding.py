@@ -163,9 +163,9 @@ def _moe_specs(cfg: ModelConfig, ax: MeshAxes, stacked: bool) -> MoEParams:
     fs = m.shared_d_ff * m.n_shared_experts if has_shared else 0
     return MoEParams(
         router=P(*L, None, None),
-        w1=P(*L, _mm(ax, m.num_experts), d_fs, None),
-        w3=P(*L, _mm(ax, m.num_experts), d_fs, None),
-        w2=P(*L, _mm(ax, m.num_experts), f_fs, None),
+        w1=P(*L, _mm(ax, m.held), d_fs, None),
+        w3=P(*L, _mm(ax, m.held), d_fs, None),
+        w2=P(*L, _mm(ax, m.held), f_fs, None),
         shared_w1=P(*L, d_fs, _mm(ax, fs)) if has_shared else None,
         shared_w3=P(*L, d_fs, _mm(ax, fs)) if has_shared else None,
         shared_w2=P(*L, _mm(ax, fs), d_fs) if has_shared else None,
