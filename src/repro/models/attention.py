@@ -4,9 +4,14 @@ The core softmax-attention primitive is a *chunked flash reference*
 (``flash_ref``): an online-softmax ``lax.scan`` over KV blocks that never
 materialises the (S, S) score matrix -- this is what the dry-runs compile
 (memory-bounded at 32k/500k context) and what the Pallas flash kernel is
-validated against.  Decode attends one new query against a KV cache; under
-pjit the cache's sequence axis is sharded over the ``model`` mesh axis and
-GSPMD inserts the cross-shard softmax reductions (flash-decode).
+validated against.  It reads K and V where they are stored: each block is a
+slice of the stored array, at its KV heads and in its stored dtype (the
+bf16 cache), against the queries grouped per KV head, with float32
+accumulation and a float32 softmax.  The cache is never widened, repeated
+to the query heads or padded to whole blocks.  Decode attends one new query
+against a KV cache; under pjit the cache's sequence axis is sharded over the
+``model`` mesh axis and GSPMD inserts the cross-shard softmax reductions
+(flash-decode).
 """
 
 from __future__ import annotations
@@ -145,32 +150,33 @@ def flash_ref(
 ) -> jax.Array:
     """Online-softmax attention over KV blocks (pure-jnp flash).
 
+    K and V are read in place: each step slices one block of the stored
+    arrays, at their ``Hkv`` heads and in their stored dtype, and contracts
+    it against the queries grouped per KV head (q viewed as (B, Sq, Hkv,
+    rep, hd)) with float32 accumulation.  q is scaled in float32 and then
+    cast to k's dtype, so a bf16 cache meets bf16 queries on the MXU; the
+    softmax state (m, l, acc) stays float32.  When ``block_kv`` does not
+    divide Sk, the last block is read from the clamped start Sk - block_kv
+    and its positions that the previous block already covered are masked,
+    so no padded copy is made.
+
     Args:
       q: (B, Sq, H, hd); k/v: (B, Sk, Hkv, hd_k/hd_v) with H % Hkv == 0.
       causal: causal masking with absolute positions (q position i attends
         kv position j iff j <= i + q_offset).
-      q_offset: absolute position of q[0] (decode: cache length).
-      kv_valid_len: optional () bound on valid kv positions (decode cache).
+      q_offset: absolute position of q[0] (decode: cache length); () or (B,).
+      kv_valid_len: optional bound on valid kv positions (decode cache);
+        () or (B,).
     """
     B, Sq, H, hd = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     rep = H // Hkv
     hv = v.shape[-1]
+    blk = min(block_kv, Sk)
+    nblk = -(-Sk // blk)
     scale = scale if scale is not None else hd ** -0.5
-    qf = jnp.asarray(q, jnp.float32) * scale
-    kf = jnp.asarray(k, jnp.float32)
-    vf = jnp.asarray(v, jnp.float32)
-    if rep > 1:
-        kf = jnp.repeat(kf, rep, axis=2)
-        vf = jnp.repeat(vf, rep, axis=2)
-
-    nblk = -(-Sk // block_kv)
-    pad = nblk * block_kv - Sk
-    if pad:
-        kf = jnp.pad(kf, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vf = jnp.pad(vf, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    kf = kf.reshape(B, nblk, block_kv, H, hd)
-    vf = vf.reshape(B, nblk, block_kv, H, hv)
+    qg = (jnp.asarray(q, jnp.float32) * scale).astype(k.dtype)
+    qg = qg.reshape(B, Sq, Hkv, rep, hd)
 
     q_offset = jnp.asarray(q_offset)
     if q_offset.ndim == 0:
@@ -183,32 +189,40 @@ def flash_ref(
         if limit.ndim == 0:
             limit = limit[None]                              # (1,) or (B,)
 
-    def body(carry, blk):
+    def body(carry, start):
         m, l, acc = carry
-        kb, vb, start = blk
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kb)           # (B, H, Sq, blk)
-        kv_pos = start + jnp.arange(block_kv)
-        mask = kv_pos[None, None, :] < limit[:, None, None]  # (B?, 1, blk)
+        # The last block of a ragged Sk starts early; positions before
+        # ``start`` were the previous block's and are masked here.
+        first = jnp.minimum(start, Sk - blk)
+        kb = jax.lax.dynamic_slice_in_dim(k, first, blk, axis=1)
+        vb = jax.lax.dynamic_slice_in_dim(v, first, blk, axis=1)
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, kb,
+                       preferred_element_type=jnp.float32)
+        kv_pos = first + jnp.arange(blk)
+        mask = ((kv_pos >= start)[None, None, :]
+                & (kv_pos[None, None, :] < limit[:, None, None]))
         if causal:
             mask = mask & (kv_pos[None, None, :] <= q_pos[:, :, None])
-        s = jnp.where(mask[:, None, :, :], s, -jnp.inf)
+        s = jnp.where(mask[:, None, None], s, -jnp.inf)     # (B, g, r, Sq, blk)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(axis=-1)
-        acc_new = acc * corr[..., None] + jnp.einsum("bhqk,bkhv->bhqv", p, vb)
+        pv = jnp.einsum("bgrqk,bkgv->bgrqv", p.astype(v.dtype), vb,
+                        preferred_element_type=jnp.float32)
+        acc_new = acc * corr[..., None] + pv
         return (m_new, l_new, acc_new), None
 
-    m0 = jnp.full((B, H, Sq), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((B, H, Sq), jnp.float32)
-    a0 = jnp.zeros((B, H, Sq, hv), jnp.float32)
-    starts = jnp.arange(nblk) * block_kv
+    m0 = jnp.full((B, Hkv, rep, Sq), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((B, Hkv, rep, Sq), jnp.float32)
+    a0 = jnp.zeros((B, Hkv, rep, Sq, hv), jnp.float32)
+    starts = jnp.arange(nblk) * blk
     if unroll:
         # Analysis mode: python loop so cost_analysis sees every block
         # (XLA counts while bodies once -- see roofline/analysis.py).
         carry = (m0, l0, a0)
         for i in range(nblk):
-            carry, _ = body(carry, (kf[:, i], vf[:, i], starts[i]))
+            carry, _ = body(carry, starts[i])
         m, l, acc = carry
     else:
         # Checkpointed body: the backward pass recomputes each block's
@@ -216,10 +230,9 @@ def flash_ref(
         # one qwen3-235b-a22b layer on 4096-token sequences over 4 v5e
         # chips, that is 15.0 GB of temp per chip instead of 20.4 GB.
         (m, l, acc), _ = jax.lax.scan(
-            jax.checkpoint(body, prevent_cse=False), (m0, l0, a0),
-            (jnp.moveaxis(kf, 1, 0), jnp.moveaxis(vf, 1, 0), starts),
-        )
-    out = acc / jnp.maximum(l[..., None], 1e-20)
+            jax.checkpoint(body, prevent_cse=False), (m0, l0, a0), starts)
+    out = acc / jnp.maximum(l[..., None], 1e-20)            # (B, g, r, Sq, hv)
+    out = out.reshape(B, H, Sq, hv)
     return jnp.moveaxis(out, 1, 2).astype(q.dtype)          # (B, Sq, H, hv)
 
 

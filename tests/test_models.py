@@ -1,5 +1,7 @@
 """Model primitives: flash oracle, decode/prefill consistency, SSD math."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,30 +33,143 @@ from repro.models.ssm import (
 B, S = 2, 36
 
 
-def _naive_attn(q, k, v, causal, rep):
+def _naive_attn(q, k, v, causal, rep, q_offset=0, kv_valid_len=None):
+    """Dense float32 attention over repeated KV heads.  q_offset and
+    kv_valid_len are () or (B,), as flash_ref takes them."""
+    q, k, v = (jnp.asarray(a, jnp.float32) for a in (q, k, v))
     kf = jnp.repeat(k, rep, 2)
     vf = jnp.repeat(v, rep, 2)
-    d = q.shape[-1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, kf) * d ** -0.5
+    Sq, Sk, d = q.shape[1], k.shape[1], q.shape[-1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kf,
+                   precision=jax.lax.Precision.HIGHEST) * d ** -0.5
+    off = jnp.broadcast_to(jnp.asarray(q_offset), (q.shape[0],))
+    lim = jnp.broadcast_to(
+        jnp.asarray(Sk if kv_valid_len is None else kv_valid_len),
+        (q.shape[0],))
+    j = jnp.arange(Sk)[None, None, :]
+    m = j < lim[:, None, None]                               # (B, Sq, Sk)
     if causal:
-        Sq = q.shape[1]
-        m = jnp.tril(jnp.ones((Sq, Sq), bool))
-        s = jnp.where(m[None, None], s, -jnp.inf)
+        m = m & (j <= jnp.arange(Sq)[None, :, None] + off[:, None, None])
+    s = jnp.where(m[:, None], s, -jnp.inf)
     p = jax.nn.softmax(s, -1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, vf)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, vf,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
-@pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("unroll", [True, False])
-def test_flash_ref_matches_naive(causal, unroll):
-    H, Hkv, hd = 4, 2, 8
-    q = jax.random.normal(jax.random.PRNGKey(0), (B, S, H, hd))
-    k = jax.random.normal(jax.random.PRNGKey(1), (B, S, Hkv, hd))
-    v = jax.random.normal(jax.random.PRNGKey(2), (B, S, Hkv, hd))
-    out = flash_ref(q, k, v, causal=causal, block_kv=16, unroll=unroll)
-    ref = _naive_attn(q, k, v, causal, H // Hkv)
-    np.testing.assert_allclose(np.array(out), np.array(ref), rtol=1e-5,
-                               atol=1e-5)
+# Each case: heads (H, Hkv), query and KV lengths, block, mask, dtype.
+# The first four keep the original causal x unroll grid (ids unroll-causal)
+# on a KV length of 36 over blocks of 16; the rest cover GQA groups of 1,
+# 12 and 16, decode (Sq 1) and chunked prefill at per-row offsets, per-row
+# valid lengths, a KV length shorter than one block, and a bf16 cache.
+_FLASH_CASES = {
+    "True-True": dict(unroll=True, causal=True),
+    "True-False": dict(unroll=True, causal=False),
+    "False-True": dict(unroll=False, causal=True),
+    "False-False": dict(unroll=False, causal=False),
+    "rep1-decode-rows": dict(H=4, Hkv=4, Sq=1, Sk=50, causal=False,
+                             kv_valid_len=(50, 7)),
+    "rep1-decode-scalar-len": dict(H=4, Hkv=4, Sq=1, Sk=50, causal=False,
+                                   kv_valid_len=23),
+    "rep12-decode-rows": dict(H=24, Hkv=2, Sq=1, Sk=40, causal=False,
+                              kv_valid_len=(40, 17)),
+    "rep16-decode-rows-unroll": dict(H=32, Hkv=2, Sq=1, Sk=45, causal=False,
+                                     kv_valid_len=(3, 45), unroll=True),
+    "rep12-prefill-offset": dict(H=24, Hkv=2, Sq=12, Sk=45, causal=True,
+                                 q_offset=(20, 5), kv_valid_len=(32, 14)),
+    "rep16-prefill-offset-unroll": dict(H=32, Hkv=2, Sq=12, Sk=45,
+                                        causal=True, q_offset=(20, 5),
+                                        kv_valid_len=(32, 17), unroll=True),
+    "rep12-short-kv": dict(H=24, Hkv=2, Sq=6, Sk=10, causal=True,
+                           q_offset=(4, 0), kv_valid_len=(10, 6)),
+    "rep12-decode-bf16": dict(H=24, Hkv=2, Sq=1, Sk=40, causal=False,
+                              kv_valid_len=(40, 17), dtype=jnp.bfloat16),
+    "rep16-prefill-bf16": dict(H=32, Hkv=2, Sq=12, Sk=45, causal=True,
+                               q_offset=(20, 5), kv_valid_len=(32, 17),
+                               dtype=jnp.bfloat16),
+    "rep1-prefill-bf16": dict(H=4, Hkv=4, Sq=12, Sk=45, causal=True,
+                              q_offset=(33, 0), kv_valid_len=(45, 12),
+                              dtype=jnp.bfloat16),
+}
+
+
+def _flash_inputs(H, Hkv, Sq, Sk, hd, dtype):
+    q = jax.random.normal(jax.random.PRNGKey(0), (B, Sq, H, hd))
+    k = jax.random.normal(jax.random.PRNGKey(1), (B, Sk, Hkv, hd))
+    v = jax.random.normal(jax.random.PRNGKey(2), (B, Sk, Hkv, hd))
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype)
+
+
+@pytest.mark.parametrize("case", list(_FLASH_CASES), ids=list(_FLASH_CASES))
+def test_flash_ref_matches_naive(case):
+    c = dict(H=4, Hkv=2, hd=8, Sq=S, Sk=S, block_kv=16, unroll=False,
+             q_offset=0, kv_valid_len=None, dtype=jnp.float32)
+    c.update(_FLASH_CASES[case])
+    q, k, v = _flash_inputs(c["H"], c["Hkv"], c["Sq"], c["Sk"], c["hd"],
+                            c["dtype"])
+    out = flash_ref(q, k, v, causal=c["causal"], block_kv=c["block_kv"],
+                    q_offset=c["q_offset"], kv_valid_len=c["kv_valid_len"],
+                    unroll=c["unroll"])
+    assert out.shape == q.shape and out.dtype == q.dtype
+    # The oracle sees the same (bf16-valued) inputs in float32: only the
+    # kernel's own rounding differs.
+    ref = _naive_attn(q, k, v, c["causal"], c["H"] // c["Hkv"],
+                      q_offset=c["q_offset"], kv_valid_len=c["kv_valid_len"])
+    tol = 1e-5 if c["dtype"] == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.array(ref),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("unroll", [False, True])
+def test_flash_ref_grad_matches_naive(unroll):
+    """Gradients through the checkpointed scan (and the unrolled loop) of
+    a grouped (rep 12), ragged (45 = 2 x 16 + 13), offset causal chunk."""
+    H, Hkv, Sq, Sk, hd = 24, 2, 12, 45, 8
+    q, k, v = _flash_inputs(H, Hkv, Sq, Sk, hd, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(3), (B, Sq, H, hd))
+    off, lim = jnp.array([20, 5]), jnp.array([32, 17])
+
+    def loss_flash(q, k, v):
+        out = flash_ref(q, k, v, causal=True, block_kv=16, q_offset=off,
+                        kv_valid_len=lim, unroll=unroll)
+        return (out * w).sum()
+
+    def loss_naive(q, k, v):
+        return (_naive_attn(q, k, v, True, H // Hkv, q_offset=off,
+                            kv_valid_len=lim) * w).sum()
+
+    got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss_naive, argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.array(g), np.array(r), rtol=1e-4,
+                                   atol=1e-4)
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_gqa_cache_read_in_place(step):
+    """The lowered decode and prefill programs hold no array over the KV
+    length (or that length padded to whole blocks) in float32 or with all
+    query heads: the bf16 cache is read at its KV heads, not widened or
+    repeated."""
+    H, Hkv, hd, Sk, blk, Bc = 12, 2, 8, 45, 16, 2          # 45 = 2 x 16 + 13
+    cfg = AttnConfig(d_model=16, num_heads=H, num_kv_heads=Hkv, head_dim=hd)
+    params = init_gqa(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    cache = KVCache(jnp.zeros((Bc, Sk, Hkv, hd), jnp.bfloat16),
+                    jnp.zeros((Bc, Sk, Hkv, hd), jnp.bfloat16),
+                    jnp.array([9, 30], jnp.int32))
+    if step == "decode":
+        x = jnp.zeros((Bc, 1, 16), jnp.bfloat16)
+        fn = lambda x, c: gqa_decode(x, c, params, cfg, block_kv=blk)  # noqa: E731
+    else:
+        x = jnp.zeros((Bc, 5, 16), jnp.bfloat16)
+        fn = lambda x, c: gqa_prefill(x, c, params, cfg, block_kv=blk)  # noqa: E731
+    text = jax.jit(fn).lower(x, cache).as_text()
+    lengths = {Sk, -(-Sk // blk) * blk}
+    types = re.findall(r"tensor<((?:\d+x)+)(\w+)>", text)
+    assert any(Sk in map(int, d.split("x")[:-1]) for d, _ in types)
+    bad = sorted({f"{d}{t}" for d, t in types
+                  if lengths & set(map(int, d.split("x")[:-1]))
+                  and (t == "f32" or H in map(int, d.split("x")[:-1]))})
+    assert not bad, bad
 
 
 def test_gqa_prefill_decode_consistency():
