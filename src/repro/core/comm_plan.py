@@ -42,8 +42,7 @@ def tier_wire_bytes(tier_tokens, d_model: int, wire_dtype: str = "none",
     The host-side mirror of the ``MoEStats.tier_bytes`` accounting: the
     planner's per-tier token volumes times the per-item payload width of
     ``wire_dtype`` (``repro.core.quantize`` -- int8 adds 4 in-band scale
-    bytes per token row).  Used by the byte-oriented rows of
-    ``benchmarks/bench_comm`` so the cost model and the device stats cannot
+    bytes per token row), so the cost model and the device stats cannot
     drift on what a wire byte is.
     """
     t = np.asarray(tier_tokens, dtype=np.int64)

@@ -28,9 +28,9 @@ would inject one token's error into another (DESIGN.md S12).
 gradient all-reduce on top of the same primitives.
 
 The byte-accounting helpers at the bottom are pure Python (no jax) so the
-host-side cost model (:mod:`repro.core.comm_plan`, ``benchmarks/bench_comm``)
-and the static verifier (:mod:`repro.analysis.plan_check`) can share one
-definition of "payload width" with the device code.
+host-side cost model (:mod:`repro.core.comm_plan`) and the static verifier
+(:mod:`repro.analysis.plan_check`) can share one definition of "payload
+width" with the device code.
 """
 
 from __future__ import annotations

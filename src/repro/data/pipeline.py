@@ -6,7 +6,7 @@ external data: each *domain* is a Zipf-distributed token source over a
 distinct vocabulary region, and the domain mixture drifts smoothly with the
 step index (plus occasional hard domain switches).  Routing through a
 learned gate on such a stream produces exactly the skewed, non-stationary
-per-expert loads of Fig. 4/5 -- see benchmarks/bench_planner.py --trace.
+per-expert loads of Fig. 4/5.
 
 Determinism: every batch is a pure function of (seed, step), so restart
 replay after a failure is bitwise identical (train/fault.py relies on it).

@@ -14,7 +14,7 @@ Modes:
 Examples:
   PYTHONPATH=src python -m repro.launch.dryrun --arch deepseek-v3-671b \
       --shape train_4k --multi-pod
-  PYTHONPATH=src python -m repro.launch.dryrun --all --out experiments/dryrun
+  PYTHONPATH=src python -m repro.launch.dryrun --all --out <dir>
 """
 
 from __future__ import annotations

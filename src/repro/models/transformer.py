@@ -56,12 +56,10 @@ class RuntimeConfig:
     distribute_chunks: int = 1
     overlap_chunks: int = 1        # MoE dispatch/compute overlap chunks
     # (repro.moe.stages); falls back to 1 per layer when the local token
-    # count is not divisible or the dispatch engine is "reference".
+    # count is not divisible.
     use_kernel: bool = False
-    dispatch_impl: str = "fused"   # "fused" | "reference" MoE dispatch engine
     wire_dtype: str = "none"       # EP wire codec: "none" | "bf16" | "int8"
-    # (repro.core.quantize, DESIGN.md S12); needs the fused engine, so it
-    # degrades to "none" when dispatch_impl == "reference".
+    # (repro.core.quantize, DESIGN.md S12).
     ffn_dtype: str = "none"        # expert FFN compute: "none" | "int8" (w8a8)
     rack_limit: int = 0            # bound each token's experts to this many
     # racks at the gate (0 = free routing, DESIGN.md S14); degrades to free
@@ -231,25 +229,20 @@ def moe_config(cfg: ModelConfig, rcfg: RuntimeConfig, pctx: ParallelCtx,
     )
     if pctx.rack_axis is not None and dispatch_mode == "a2a":
         dispatch_mode = "hier_a2a"   # factored mesh: tiered token exchange
-    # Overlap chunking must divide the per-rank token count and needs the
-    # fused engine (the reference path is the unchunked oracle); rather
-    # than fail deep inside a scanned block, degrade to unchunked here.
+    # Overlap chunking must divide the per-rank token count; rather than
+    # fail deep inside a scanned block, degrade to unchunked here.
     overlap = rcfg.overlap_chunks
-    if overlap >= 1 and (tokens_per_rank % overlap != 0
-                         or rcfg.dispatch_impl != "fused"):
+    if overlap >= 1 and tokens_per_rank % overlap != 0:
         overlap = 1   # overlap < 1 passes through to MoEConfig's validation
-    # The wire codec rides the fused engine's packed buffers; like overlap,
-    # degrade rather than fail when the reference oracle engine is selected.
-    wire_dtype = rcfg.wire_dtype if rcfg.dispatch_impl == "fused" else "none"
     return MoEConfig(
         gating=gating, balancer=bal, d_model=cfg.d_model, d_ff=m.d_ff,
         ep_size=ep, cap_pair=cap_pair, cap_slot=cap_slot,
         n_shared_experts=m.n_shared_experts, shared_d_ff=m.shared_d_ff,
         distribute_chunks=rcfg.distribute_chunks, overlap_chunks=overlap,
         use_kernel=rcfg.use_kernel,
-        dispatch_mode=dispatch_mode, dispatch_impl=rcfg.dispatch_impl,
+        dispatch_mode=dispatch_mode,
         racks=pctx.racks,
-        wire_dtype=wire_dtype, ffn_dtype=rcfg.ffn_dtype,
+        wire_dtype=rcfg.wire_dtype, ffn_dtype=rcfg.ffn_dtype,
     )
 
 

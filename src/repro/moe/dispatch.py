@@ -1,6 +1,9 @@
-"""EP token dispatch/combine: capacity-bounded all-to-all under shard_map.
+"""Multi-sort EP token dispatch/combine: the oracle of the fused engine.
 
-This is the DeepEP analogue on TPU (DESIGN.md S2).  Per rank, inside
+The production layer dispatches with the single-sort engine of
+:mod:`repro.moe.permute`; this module keeps the multi-sort scatter path it
+replaced, and :func:`moe_layer_reference` runs a whole layer on it so tests
+can hold the engine to it bit for bit (DESIGN.md S2).  Per rank, inside
 ``shard_map`` over the EP ("model") axis:
 
   1. gate locally, all_gather per-expert counts -> exact load matrix Lambda;
@@ -30,9 +33,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.planner import occurrence_index, token_targets
+from repro.moe import stages
+from repro.moe.expert import grouped_ffn
+from repro.moe.reference import swiglu
 
 __all__ = ["DispatchOut", "dispatch_tokens", "combine_tokens", "bucket_by_slot",
-           "unbucket"]
+           "unbucket", "moe_layer_reference"]
 
 _I32 = jnp.int32
 
@@ -180,3 +186,92 @@ def combine_tokens(
     )[:, None].astype(ret_x.dtype)
     y = jnp.zeros((num_tokens, D), ret_x.dtype)
     return y.at[items_t].add(vals)
+
+
+def moe_layer_reference(x: jax.Array, params, cfg, *,
+                        axis_name: str | None
+                        ) -> tuple[jax.Array, jax.Array, stages.MoEStats]:
+    """One MoE layer on the multi-sort path (call under shard_map).
+
+    Runs the production gate, plan and distribute stages, then dispatches
+    with :func:`dispatch_tokens` -> all_to_all -> :func:`bucket_by_slot`
+    (replicated: each rank keeps the items ``token_targets`` gives it under
+    the one-source split ``u``), applies the grouped FFN to the main and
+    replica slot ranges, and returns the outputs through :func:`unbucket` ->
+    all_to_all -> :func:`combine_tokens`.  Same signature and results as
+    :func:`repro.moe.layer.moe_layer_local`, for flat EP only: unchunked,
+    with no wire codec and no expert share.
+    """
+    if (cfg.dispatch_mode not in ("a2a", "replicated") or cfg.racks != 1
+            or cfg.overlap_chunks != 1 or cfg.wire_dtype != "none"
+            or cfg.gating.holds_share):
+        raise ValueError(
+            "moe_layer_reference models flat EP (dispatch_mode 'a2a' or "
+            "'replicated', racks=1), unchunked (overlap_chunks=1), with no "
+            "wire codec (wire_dtype='none') and no expert share")
+    ctx = stages.make_stage_ctx(cfg, axis_name)
+    gs = stages.gate_stage(ctx, x, params.router)
+    ps = stages.plan_stage(ctx, gs)
+    dist = stages.distribute_stage(ctx, params, gs, ps)
+    expert_ids, weights = gs.gate_out.expert_ids, gs.gate_out.weights
+    num_slots = cfg.layout.slots_per_rank
+    slot_of = ps.slot_of_all[gs.my]                        # (E,)
+    T, D = x.shape
+
+    if cfg.dispatch_mode == "replicated":
+        items_e = expert_ids.reshape(-1)
+        # (T*k,): u is the one-source split.
+        owner = token_targets(items_e, ps.plan.u)
+        mine = owner == gs.my
+        recv_e = jnp.where(mine, items_e, -1)[None, :]       # (1, T*k)
+        recv_x = jnp.repeat(x, cfg.gating.top_k, axis=0)[None, :, :]
+        drops_dispatch = jnp.zeros((), _I32)
+    else:
+        q_row = ps.plan.q[gs.my]                           # (E, R)
+        disp = dispatch_tokens(x, expert_ids, q_row, cap_pair=cfg.cap_pair)
+        if axis_name is not None:
+            recv_x = jax.lax.all_to_all(disp.send_x, axis_name, 0, 0,
+                                        tiled=False)
+            recv_e = jax.lax.all_to_all(disp.send_e, axis_name, 0, 0,
+                                        tiled=False)
+        else:
+            recv_x, recv_e = disp.send_x, disp.send_e
+        drops_dispatch = disp.drops
+    xs, valid, back_idx, drops_slot = bucket_by_slot(
+        recv_x, recv_e, slot_of, num_slots=num_slots, cap_slot=cfg.cap_slot)
+
+    n_main = dist.w1.shape[0]
+    out = jnp.concatenate([
+        grouped_ffn(xs[:n_main], valid[:n_main], dist.w1, dist.w3, dist.w2,
+                    use_kernel=cfg.use_kernel, ffn_dtype=cfg.ffn_dtype),
+        grouped_ffn(xs[n_main:], valid[n_main:], dist.w1r, dist.w3r,
+                    dist.w2r, use_kernel=cfg.use_kernel,
+                    ffn_dtype=cfg.ffn_dtype),
+    ], axis=0)
+
+    if cfg.dispatch_mode == "replicated":
+        k = weights.shape[1]
+        ret = unbucket(out, valid, back_idx, (1, T * k, D))
+        items_t = jnp.repeat(jnp.arange(T, dtype=_I32), k)
+        vals = ret[0] * weights.reshape(-1)[:, None].astype(ret.dtype)
+        y = jnp.zeros((T, D), ret.dtype).at[items_t].add(vals)
+        if axis_name is not None:
+            y = jax.lax.psum(y, axis_name)
+    else:
+        ret = unbucket(out, valid, back_idx, (cfg.ep_size, cfg.cap_pair, D))
+        if axis_name is not None:
+            ret = jax.lax.all_to_all(ret, axis_name, 0, 0, tiled=False)
+        y = combine_tokens(ret, disp, weights, T)
+
+    if cfg.n_shared_experts > 0:
+        y = y + swiglu(x, params.shared_w1, params.shared_w3,
+                       params.shared_w2)
+    stats = stages.MoEStats(
+        drops_dispatch=drops_dispatch,
+        drops_slot=drops_slot,
+        pre_max=ps.plan.pre_max,
+        post_max=ps.plan.post_max,
+        max_slot_load=valid.sum(axis=1).max().astype(_I32),
+        counts=gs.gate_out.counts,
+    )
+    return y.astype(x.dtype), gs.gate_out.aux_loss, stats

@@ -63,18 +63,15 @@ class MoEConfig:
     # all_to_all, no pair capacities, no drops at pair granularity.
     # "hier_a2a": two-level (rack x lane) EP -- the rack-aware plan solve,
     # the two-hop token exchange and the tiered replica streaming of
-    # DESIGN.md S9.  Requires the fused engine and a factored
-    # (rack_axis, lane_axis) mesh; bit-identical to "a2a" on one rack.
-    dispatch_impl: str = "fused"   # "fused" (single-sort permutation engine,
-    # repro.moe.permute) | "reference" (multi-sort scatter path,
-    # repro.moe.dispatch -- kept as the equivalence oracle)
+    # DESIGN.md S9.  Requires a factored (rack_axis, lane_axis) mesh;
+    # bit-identical to "a2a" on one rack.
     racks: int = 1                 # racks of the two-level EP group
     wire_dtype: str = "none"       # EP-wire payload codec (DESIGN.md S12):
     # "none" (native dtype, bit-exact oracle path) | "bf16" | "int8"
     # (per-row symmetric, fp32 scales packed in-band).  Covers the token
     # all_to_all (both directions) and the replica weight stream; routing,
     # counts and slot placement are computed BEFORE encoding and are
-    # bit-identical across wire dtypes.  Fused engine only.
+    # bit-identical across wire dtypes.
     ffn_dtype: str = "none"        # expert FFN compute dtype: "none" (fp
     # reference, default) | "int8" (w8a8 grouped SwiGLU, per-token-row
     # activation scales x per-(expert, out-feature) weight scales).  With
@@ -83,14 +80,8 @@ class MoEConfig:
 
     def __post_init__(self):
         # Fail at construction, not at trace time (DESIGN.md S9).
-        if self.dispatch_impl not in ("fused", "reference"):
-            raise ValueError(f"unknown dispatch_impl: {self.dispatch_impl!r}")
         if self.dispatch_mode not in ("a2a", "replicated", "hier_a2a"):
             raise ValueError(f"unknown dispatch_mode: {self.dispatch_mode!r}")
-        if self.dispatch_mode == "hier_a2a" and self.dispatch_impl != "fused":
-            raise ValueError(
-                "dispatch_mode='hier_a2a' requires dispatch_impl='fused' "
-                "(the reference scatter path is the flat-EP oracle)")
         if self.racks < 1 or self.ep_size % self.racks != 0:
             raise ValueError(
                 f"racks={self.racks} must divide ep_size={self.ep_size}")
@@ -100,23 +91,12 @@ class MoEConfig:
         if self.overlap_chunks < 1:
             raise ValueError(
                 f"overlap_chunks={self.overlap_chunks} must be >= 1")
-        if self.overlap_chunks > 1 and self.dispatch_impl != "fused":
-            raise ValueError(
-                "overlap_chunks > 1 requires dispatch_impl='fused' (the "
-                "reference scatter path is the unchunked equivalence oracle)")
         if self.wire_dtype not in ("none", "bf16", "int8"):
             raise ValueError(f"unknown wire_dtype: {self.wire_dtype!r}")
         if self.ffn_dtype not in ("none", "int8"):
             raise ValueError(f"unknown ffn_dtype: {self.ffn_dtype!r}")
-        if self.wire_dtype != "none" and self.dispatch_impl != "fused":
-            raise ValueError(
-                "wire_dtype != 'none' requires dispatch_impl='fused' (the "
-                "reference scatter path is the uncompressed oracle)")
-        if self.gating.holds_share and (self.dispatch_impl != "fused"
-                                        or self.racks != 1):
-            raise ValueError(
-                "an expert share runs flat EP on the fused engine "
-                "(dispatch_impl='fused', racks=1)")
+        if self.gating.holds_share and self.racks != 1:
+            raise ValueError("an expert share runs flat EP (racks=1)")
 
     @property
     def ranks_per_rack(self) -> int:
@@ -217,7 +197,7 @@ def moe_layer_local(
     Thin wrapper over :func:`repro.moe.stages.run_staged_moe` -- the staged
     driver composes gate/plan/distribute (once per microbatch) with the
     per-chunk dispatch/compute/combine tail according to
-    ``cfg.dispatch_mode``, ``cfg.dispatch_impl`` and ``cfg.overlap_chunks``.
+    ``cfg.dispatch_mode`` and ``cfg.overlap_chunks``.
 
     Args:
       x: (T_local, D) this rank's tokens.

@@ -45,18 +45,11 @@ from repro.analysis.plan_check import PlanViolationError
 from repro.core import balancer as balancer_mod
 from repro.core.layout import physical_slot_of
 from repro.fault.injector import PlannerFault, SolveTimeout, TransferFault
-from repro.core.planner import token_targets
 from repro.core.quantize import (
     decode_wire,
     encode_wire,
     payload_bytes_per_item,
     split_wire_int8,
-)
-from repro.moe.dispatch import (
-    bucket_by_slot,
-    combine_tokens,
-    dispatch_tokens,
-    unbucket,
 )
 from repro.moe.distribute import materialize_replica_stack
 from repro.moe.expert import grouped_ffn
@@ -179,9 +172,8 @@ class DispatchState(NamedTuple):
 
     ``xs``/``valid`` are the slot buffers ComputeStage consumes; ``inverse``
     is the mode-specific state CombineStage needs to route FFN outputs back
-    (fused a2a: (FusedDispatch, BucketMeta); reference a2a: (DispatchOut,
-    back_idx); fused replicated: ReplicatedBucket; reference replicated:
-    back_idx).  Stages communicate ONLY through these fields -- the
+    (a2a and hier_a2a: (FusedDispatch, BucketMeta); replicated:
+    ReplicatedBucket).  Stages communicate ONLY through these fields -- the
     stage-boundary lint rule (DESIGN.md S11) keeps the underlying engine
     primitives from being called outside this module.
     """
@@ -287,13 +279,11 @@ class Resilience:
                        relay_threshold: int = 3, topology=None):
         """Build the plan's replica broadcast schedule under LIVE speeds.
 
-        Host-side companion of :func:`distribute_stage` for runners that
-        model or drive the replica stream explicitly (serving warm-up,
-        benchmarks, the CI fault sweep): previously those called
-        ``build_relay_schedule`` health-blind and only the simulator saw
-        ``rank_speed``; routing the construction through the layer's
-        :class:`Resilience` makes the tree itself health-aware.  ``plan``
-        is a solved (concrete) Plan; ``home`` the (E,) home map.
+        Host-side companion of :func:`distribute_stage` for a runner that
+        models or drives the replica stream explicitly: routing
+        ``build_relay_schedule`` through the layer's :class:`Resilience`
+        makes the tree itself health-aware.  ``plan`` is a solved
+        (concrete) Plan; ``home`` the (E,) home map.
         """
         import numpy as np
 
@@ -602,73 +592,41 @@ def dispatch_stage(ctx: StageCtx, x_chunk: jax.Array,
         # Tokens identical on every EP rank (decode / exact-reference path):
         # item j of expert e is owned by the instance whose cumulative quota
         # covers j; this rank computes its share, outputs are psum-merged.
-        slot_of = ps.slot_of_all[gs.my]
-        if cfg.dispatch_impl == "fused":
-            rb = fused_replicated_bucket(
-                x_chunk, expert_ids, ps.plan.cum_u, gs.my, slot_of,
-                num_slots=num_slots, cap_slot=cfg.cap_slot,
-                occ_offset=occ_offset, routed=routed,
-            )
-            return DispatchState(xs=rb.xs, valid=rb.valid, inverse=rb,
-                                 drops_dispatch=zero, drops_slot=rb.drops)
-        items_e = expert_ids.reshape(-1)
-        # (Tc*k,): u is the one-source split.
-        owner = token_targets(items_e, ps.plan.u)
-        mine = owner == gs.my
-        recv_e = jnp.where(mine, items_e, -1)[None, :]       # (1, Tc*k)
-        recv_x = jnp.repeat(x_chunk, cfg.gating.top_k, axis=0)[None, :, :]
-        xs, valid, back_idx, slot_drops = bucket_by_slot(
-            recv_x, recv_e, slot_of, num_slots=num_slots,
-            cap_slot=cfg.cap_slot
+        rb = fused_replicated_bucket(
+            x_chunk, expert_ids, ps.plan.cum_u, gs.my, ps.slot_of_all[gs.my],
+            num_slots=num_slots, cap_slot=cfg.cap_slot,
+            occ_offset=occ_offset, routed=routed,
         )
-        return DispatchState(xs=xs, valid=valid, inverse=back_idx,
-                             drops_dispatch=zero, drops_slot=slot_drops)
+        return DispatchState(xs=rb.xs, valid=rb.valid, inverse=rb,
+                             drops_dispatch=zero, drops_slot=rb.drops)
 
-    if cfg.dispatch_impl == "fused":
-        # Single-sort permutation engine (repro.moe.permute): on a factored
-        # mesh the same destination-major buffers ride the two-hop tiered
-        # exchange; the count metadata rides both hops unchanged.  The
-        # payload is wire-encoded BEFORE the first hop (quantization happens
-        # once, at the source; the intra-rack scatter of the two-hop wire
-        # moves the already-encoded bytes) and decoded only after bucketing.
-        # Routing lives entirely in the count metadata, so token placement
-        # is bit-identical across wire dtypes (DESIGN.md S12).
-        disp = fused_dispatch(
-            x_chunk, expert_ids, ps.plan.cum_q[gs.my], ps.slot_of_all,
-            num_slots=num_slots, cap_pair=cfg.cap_pair, occ_offset=occ_offset,
-            routed=routed,
-        )
-        recv_x = _exchange(ctx, encode_wire(disp.send_x, cfg.wire_dtype))
-        recv_c = _exchange(ctx, disp.send_counts)
-        xs, valid, meta, slot_drops = fused_bucket(
-            recv_x, recv_c, num_slots=num_slots, cap_slot=cfg.cap_slot
-        )
-        xs_scale = None
-        if cfg.wire_dtype == "int8" and cfg.ffn_dtype == "int8":
-            # End-to-end quantized: hand ComputeStage the codes + scales.
-            xs, xs_scale = split_wire_int8(xs)
-        else:
-            xs = decode_wire(xs, cfg.wire_dtype, x_chunk.dtype)
-        return DispatchState(xs=xs, valid=valid, inverse=(disp, meta),
-                             drops_dispatch=disp.drops, drops_slot=slot_drops,
-                             xs_scale=xs_scale)
-
-    # Reference multi-sort scatter path (the equivalence oracle; unchunked).
-    q_row = ps.plan.q[gs.my]                               # (E, R)
-    disp = dispatch_tokens(x_chunk, expert_ids, q_row, cap_pair=cfg.cap_pair)
-    if ctx.axis_name is not None:
-        recv_x = jax.lax.all_to_all(disp.send_x, ctx.axis_name, 0, 0,
-                                    tiled=False)
-        recv_e = jax.lax.all_to_all(disp.send_e, ctx.axis_name, 0, 0,
-                                    tiled=False)
-    else:
-        recv_x, recv_e = disp.send_x, disp.send_e
-    slot_of = ps.slot_of_all[gs.my]                        # (E,)
-    xs, valid, back_idx, slot_drops = bucket_by_slot(
-        recv_x, recv_e, slot_of, num_slots=num_slots, cap_slot=cfg.cap_slot
+    # Single-sort permutation engine (repro.moe.permute): on a factored
+    # mesh the same destination-major buffers ride the two-hop tiered
+    # exchange; the count metadata rides both hops unchanged.  The
+    # payload is wire-encoded BEFORE the first hop (quantization happens
+    # once, at the source; the intra-rack scatter of the two-hop wire
+    # moves the already-encoded bytes) and decoded only after bucketing.
+    # Routing lives entirely in the count metadata, so token placement
+    # is bit-identical across wire dtypes (DESIGN.md S12).
+    disp = fused_dispatch(
+        x_chunk, expert_ids, ps.plan.cum_q[gs.my], ps.slot_of_all,
+        num_slots=num_slots, cap_pair=cfg.cap_pair, occ_offset=occ_offset,
+        routed=routed,
     )
-    return DispatchState(xs=xs, valid=valid, inverse=(disp, back_idx),
-                         drops_dispatch=disp.drops, drops_slot=slot_drops)
+    recv_x = _exchange(ctx, encode_wire(disp.send_x, cfg.wire_dtype))
+    recv_c = _exchange(ctx, disp.send_counts)
+    xs, valid, meta, slot_drops = fused_bucket(
+        recv_x, recv_c, num_slots=num_slots, cap_slot=cfg.cap_slot
+    )
+    xs_scale = None
+    if cfg.wire_dtype == "int8" and cfg.ffn_dtype == "int8":
+        # End-to-end quantized: hand ComputeStage the codes + scales.
+        xs, xs_scale = split_wire_int8(xs)
+    else:
+        xs = decode_wire(xs, cfg.wire_dtype, x_chunk.dtype)
+    return DispatchState(xs=xs, valid=valid, inverse=(disp, meta),
+                         drops_dispatch=disp.drops, drops_slot=slot_drops,
+                         xs_scale=xs_scale)
 
 
 def compute_stage(ctx: StageCtx, ds: DispatchState,
@@ -702,36 +660,20 @@ def combine_stage(ctx: StageCtx, ds: DispatchState,
     ``outs`` is ComputeStage's slot outputs; ``weights`` is the (T_chunk, k)
     gate-weight slice of this chunk; the return is the chunk's (T_chunk, D)
     combined output (pre-psum for the replicated mode -- run_staged_moe merges
-    ranks once over the whole batch).  The fused engine gathers from the
-    slot ranges as they are; the reference oracle scatters from their
-    concatenation.
+    ranks once over the whole batch).  Both modes gather from the slot
+    ranges as they are, without concatenating them.
     """
     cfg = ctx.cfg
-    if cfg.dispatch_impl == "fused":
-        if cfg.dispatch_mode == "replicated":
-            return fused_replicated_combine(outs, ds.inverse, weights)
-        # The return wire carries the same codec as the forward wire: FFN
-        # outputs are encoded per-row before the reverse exchange and decoded
-        # at the source rank, right before the weighted reduce.
-        disp, meta = ds.inverse
-        ret = _exchange(ctx, encode_wire(fused_unbucket(outs, meta),
-                                         cfg.wire_dtype), reverse=True)
-        return fused_combine(decode_wire(ret, cfg.wire_dtype, outs[0].dtype),
-                             disp, weights)
-    out = jnp.concatenate(outs, axis=0)
-    D = out.shape[-1]
     if cfg.dispatch_mode == "replicated":
-        Tc, k = weights.shape
-        ret = unbucket(out, ds.valid, ds.inverse, (1, Tc * k, D))
-        flat_w = weights.reshape(-1)
-        items_t = jnp.repeat(jnp.arange(Tc, dtype=_I32), k)
-        vals = ret[0] * flat_w[:, None].astype(ret.dtype)
-        return jnp.zeros((Tc, D), ret.dtype).at[items_t].add(vals)
-    disp, back_idx = ds.inverse
-    ret = unbucket(out, ds.valid, back_idx, (cfg.ep_size, cfg.cap_pair, D))
-    if ctx.axis_name is not None:
-        ret = jax.lax.all_to_all(ret, ctx.axis_name, 0, 0, tiled=False)
-    return combine_tokens(ret, disp, weights, weights.shape[0])
+        return fused_replicated_combine(outs, ds.inverse, weights)
+    # The return wire carries the same codec as the forward wire: FFN
+    # outputs are encoded per-row before the reverse exchange and decoded
+    # at the source rank, right before the weighted reduce.
+    disp, meta = ds.inverse
+    ret = _exchange(ctx, encode_wire(fused_unbucket(outs, meta),
+                                     cfg.wire_dtype), reverse=True)
+    return fused_combine(decode_wire(ret, cfg.wire_dtype, outs[0].dtype),
+                         disp, weights)
 
 
 # --------------------------------------------------------------------------

@@ -577,14 +577,14 @@ def test_verify_chunking_rejects_bad_shape(valid_plan):
 # eval_shape dry-trace of the MoE dispatch paths
 # ======================================================================
 
-def _moe_cfg(E, D, F, T, *, top_k=2, impl="fused", mode="ultraep"):
+def _moe_cfg(E, D, F, T, *, top_k=2, mode="ultraep"):
     from repro.moe.gating import GatingConfig
     from repro.moe.layer import MoEConfig
     return MoEConfig(
         gating=GatingConfig(num_experts=E, top_k=top_k),
         balancer=BalancerConfig(mode=mode, n_slot=2),
         d_model=D, d_ff=F, ep_size=1,
-        cap_pair=T * top_k, cap_slot=T * top_k, dispatch_impl=impl)
+        cap_pair=T * top_k, cap_slot=T * top_k)
 
 
 @pytest.mark.parametrize("shape", [
@@ -595,16 +595,19 @@ def _moe_cfg(E, D, F, T, *, top_k=2, impl="fused", mode="ultraep"):
 def test_eval_shape_moe_layer(shape, impl):
     """Abstractly trace the full MoE layer (gate -> solve -> dispatch ->
     FFN -> combine) for shape/dtype consistency without touching a device
-    or allocating parameters."""
+    or allocating parameters.  ``reference`` traces the multi-sort oracle
+    :func:`repro.moe.dispatch.moe_layer_reference`."""
+    from repro.moe.dispatch import moe_layer_reference
     from repro.moe.layer import init_moe_params, moe_layer_local
 
+    layer = {"fused": moe_layer_local, "reference": moe_layer_reference}[impl]
     E, D, F, T = shape
-    cfg = _moe_cfg(E, D, F, T, impl=impl)
+    cfg = _moe_cfg(E, D, F, T)
     key = jax.random.PRNGKey(0)
     params = jax.eval_shape(lambda k: init_moe_params(k, cfg), key)
     x = jax.ShapeDtypeStruct((T, D), jnp.float32)
     y, aux, stats = jax.eval_shape(
-        lambda xx, pp: moe_layer_local(xx, pp, cfg, axis_name=None),
+        lambda xx, pp: layer(xx, pp, cfg, axis_name=None),
         x, params)
     assert y.shape == (T, D) and y.dtype == jnp.float32
     assert aux.shape == ()

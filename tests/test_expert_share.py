@@ -190,13 +190,12 @@ def test_block_must_lie_inside_the_router(held, first):
                      first_expert=first)
 
 
-def test_share_needs_the_fused_engine():
-    with pytest.raises(ValueError, match="fused"):
+def test_share_needs_flat_ep():
+    with pytest.raises(ValueError, match="flat EP"):
         MoEConfig(gating=GatingConfig(num_experts=E, top_k=K,
                                       held_experts=BLOCK),
                   balancer=BalancerConfig(n_slot=2), d_model=D, d_ff=F,
-                  ep_size=1, cap_pair=8, cap_slot=8,
-                  dispatch_impl="reference")
+                  ep_size=2, cap_pair=8, cap_slot=8, racks=2)
 
 
 # ------------------------------------- shares over an EP group of 2 ------

@@ -1,11 +1,13 @@
 """Fused single-sort dispatch engine vs the reference multi-sort path.
 
 The contract (DESIGN.md S2): at capacities sized for zero drops, the fused
-engine is **bit-identical** to the reference scatter path for the full MoE
-layer -- same buffers' contents per slot, row-independent grouped FFN, and a
-combine that folds the k contributions of each token in the same order.  At
-tight capacities both paths drop, and the fused path's accounting must
-conserve items end to end.
+engine is **bit-identical** to the reference scatter path
+(:func:`repro.moe.dispatch.moe_layer_reference`) for the full MoE layer --
+same buffers' contents per slot, row-independent grouped FFN, and a combine
+that folds the k contributions of each token in the same order.  The
+reference itself is pinned to the dense per-token oracle.  At tight
+capacities both paths drop, and the fused path's accounting must conserve
+items end to end.
 """
 
 import jax
@@ -22,24 +24,25 @@ from repro.moe.dispatch import (
     bucket_by_slot,
     combine_tokens,
     dispatch_tokens,
+    moe_layer_reference,
     unbucket,
 )
 from repro.moe.gating import GatingConfig, gate
 from repro.moe.layer import MoEConfig, init_moe_params, moe_layer_local
+from repro.moe.reference import moe_ref
 
 E, D, F, T = 8, 16, 32, 64
 
 MODES = ["none", "ultraep", "eplb_plus"]
 
 
-def _cfg(mode, impl, *, top_k=2, cap_pair=None, cap_slot=None, **kw):
+def _cfg(mode, *, top_k=2, cap_pair=None, cap_slot=None, **kw):
     return MoEConfig(
         gating=GatingConfig(num_experts=E, top_k=top_k),
         balancer=BalancerConfig(mode=mode, n_slot=2),
         d_model=D, d_ff=F, ep_size=1,
         cap_pair=T * top_k if cap_pair is None else cap_pair,
-        cap_slot=T * top_k if cap_slot is None else cap_slot,
-        dispatch_impl=impl, **kw)
+        cap_slot=T * top_k if cap_slot is None else cap_slot, **kw)
 
 
 def _layer(cfg, seed=0):
@@ -55,11 +58,10 @@ def _layer(cfg, seed=0):
 @pytest.mark.parametrize("mode", MODES)
 def test_fused_layer_bitwise_equals_reference(mode, top_k, seed):
     """Zero-drop capacities: fused == reference, bit for bit."""
-    params, x = _layer(_cfg(mode, "fused", top_k=top_k), seed)
-    y_f, aux_f, st_f = moe_layer_local(
-        x, params, _cfg(mode, "fused", top_k=top_k), axis_name=None)
-    y_r, aux_r, st_r = moe_layer_local(
-        x, params, _cfg(mode, "reference", top_k=top_k), axis_name=None)
+    cfg = _cfg(mode, top_k=top_k)
+    params, x = _layer(cfg, seed)
+    y_f, aux_f, st_f = moe_layer_local(x, params, cfg, axis_name=None)
+    y_r, aux_r, st_r = moe_layer_reference(x, params, cfg, axis_name=None)
     assert int(st_f.drops_dispatch) == 0 and int(st_f.drops_slot) == 0
     assert int(st_r.drops_dispatch) == 0 and int(st_r.drops_slot) == 0
     assert np.array_equal(np.array(y_f), np.array(y_r))
@@ -69,31 +71,45 @@ def test_fused_layer_bitwise_equals_reference(mode, top_k, seed):
 
 @pytest.mark.parametrize("mode", MODES)
 def test_fused_replicated_bitwise_equals_reference(mode):
-    params, x = _layer(_cfg(mode, "fused", dispatch_mode="replicated"))
-    y_f, _, st_f = moe_layer_local(
-        x, params, _cfg(mode, "fused", dispatch_mode="replicated"),
-        axis_name=None)
-    y_r, _, st_r = moe_layer_local(
-        x, params, _cfg(mode, "reference", dispatch_mode="replicated"),
-        axis_name=None)
+    cfg = _cfg(mode, dispatch_mode="replicated")
+    params, x = _layer(cfg)
+    y_f, _, st_f = moe_layer_local(x, params, cfg, axis_name=None)
+    y_r, _, st_r = moe_layer_reference(x, params, cfg, axis_name=None)
     assert int(st_f.drops_slot) == 0 and int(st_r.drops_slot) == 0
     assert np.array_equal(np.array(y_f), np.array(y_r))
 
 
 def test_fused_gradients_match_reference():
-    cfg_f, cfg_r = _cfg("ultraep", "fused"), _cfg("ultraep", "reference")
-    params, x = _layer(cfg_f)
+    cfg = _cfg("ultraep")
+    params, x = _layer(cfg)
 
-    def loss(cfg):
+    def loss(layer):
         def f(x):
-            y, aux, _ = moe_layer_local(x, params, cfg, axis_name=None)
+            y, aux, _ = layer(x, params, cfg, axis_name=None)
             return (y ** 2).sum() + aux
         return f
 
-    g_f = jax.grad(loss(cfg_f))(x)
-    g_r = jax.grad(loss(cfg_r))(x)
+    g_f = jax.grad(loss(moe_layer_local))(x)
+    g_r = jax.grad(loss(moe_layer_reference))(x)
     np.testing.assert_allclose(np.array(g_f), np.array(g_r), rtol=1e-5,
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("dispatch_mode", ["a2a", "replicated"])
+def test_reference_layer_matches_dense_oracle(dispatch_mode):
+    """Zero-drop capacities: the multi-sort oracle computes the dense
+    per-token MoE of :func:`repro.moe.reference.moe_ref`."""
+    cfg = _cfg("ultraep", dispatch_mode=dispatch_mode, n_shared_experts=1,
+               shared_d_ff=F)
+    params, x = _layer(cfg)
+    y, _, stats = moe_layer_reference(x, params, cfg, axis_name=None)
+    assert int(stats.drops_dispatch) == 0 and int(stats.drops_slot) == 0
+    g = gate(x, params.router, cfg.gating)
+    y_ref = moe_ref(x, g.expert_ids, g.weights, params.w1, params.w3,
+                    params.w2, shared=(params.shared_w1, params.shared_w3,
+                                       params.shared_w2))
+    np.testing.assert_allclose(np.array(y), np.array(y_ref), rtol=1e-5,
+                               atol=1e-5)
 
 
 # ------------------------------------------- multi-rank (simulated) -----
@@ -192,7 +208,7 @@ def test_fused_drop_accounting_tight_caps():
 
 
 def test_fused_layer_tight_caps_drops_counted():
-    cfg = _cfg("none", "fused", cap_slot=4)
+    cfg = _cfg("none", cap_slot=4)
     params, x = _layer(cfg)
     y, _, stats = moe_layer_local(x, params, cfg, axis_name=None)
     assert int(stats.drops_slot) > 0
@@ -200,7 +216,7 @@ def test_fused_layer_tight_caps_drops_counted():
 
 
 def test_fused_replicated_tight_caps_drops_counted():
-    cfg = _cfg("none", "fused", dispatch_mode="replicated", cap_slot=4)
+    cfg = _cfg("none", dispatch_mode="replicated", cap_slot=4)
     params, x = _layer(cfg)
     y, _, stats = moe_layer_local(x, params, cfg, axis_name=None)
     assert int(stats.drops_slot) > 0
@@ -228,6 +244,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.balancer import BalancerConfig
 from repro.moe.gating import GatingConfig
+from repro.moe.dispatch import moe_layer_reference
 from repro.moe.layer import MoEConfig, MoEParams, moe_layer_local
 
 R, E, kk, D, F, T = 4, 16, 4, 16, 24, 32 * 4
@@ -240,14 +257,14 @@ w2 = jax.random.normal(pk[3], (E, F, D)) * F**-0.5
 x = jax.random.normal(pk[4], (T, D))
 gcfg = GatingConfig(num_experts=E, top_k=kk)
 
+cfg = MoEConfig(gating=gcfg,
+                balancer=BalancerConfig(mode="ultraep", n_slot=2),
+                d_model=D, d_ff=F, ep_size=R, cap_pair=T*kk, cap_slot=T*kk)
 ys = {}
-for impl in ["fused", "reference"]:
-    cfg = MoEConfig(gating=gcfg,
-                    balancer=BalancerConfig(mode="ultraep", n_slot=2),
-                    d_model=D, d_ff=F, ep_size=R, cap_pair=T*kk,
-                    cap_slot=T*kk, dispatch_impl=impl)
+for impl, layer in [("fused", moe_layer_local),
+                    ("reference", moe_layer_reference)]:
     def run(x, router, w1, w3, w2):
-        y, aux, stats = moe_layer_local(
+        y, aux, stats = layer(
             x, MoEParams(router, w1, w3, w2), cfg, axis_name="model")
         return y, (stats.drops_dispatch + stats.drops_slot)[None]
     f = jax.shard_map(run, mesh=mesh, check_vma=False,

@@ -268,10 +268,7 @@ def test_layer_wire_dtypes_same_routing_close_output():
             err_msg=f"wire={wire} ffn={ffn}")
 
 
-def test_wire_dtype_requires_fused_dispatch():
-    with pytest.raises(ValueError, match="wire_dtype"):
-        dataclasses.replace(_layer_cfg(8, 16, 32, 64, wire="int8"),
-                            dispatch_impl="reference")
+def test_wire_and_ffn_dtype_validation():
     with pytest.raises(ValueError, match="wire_dtype"):
         _layer_cfg(8, 16, 32, 64, wire="fp8")
     with pytest.raises(ValueError, match="ffn_dtype"):

@@ -57,13 +57,6 @@ def test_rejects_negative_distribute_chunks():
         _cfg(distribute_chunks=0)
 
 
-def test_rejects_overlap_with_reference_impl():
-    """The reference scatter path is the unchunked equivalence oracle; it
-    never runs chunked."""
-    with pytest.raises(ValueError, match="fused"):
-        _cfg(overlap_chunks=2, dispatch_impl="reference")
-
-
 def test_rejects_indivisible_chunk_count(setup):
     _, params, x = setup
     cfg = _cfg(overlap_chunks=3)           # 64 % 3 != 0: caught at trace time
