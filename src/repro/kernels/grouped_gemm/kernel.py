@@ -1,16 +1,23 @@
-"""Grouped GEMM Pallas kernel: per-expert-slot batched matmul.
+"""Grouped GEMM Pallas kernels: per-expert-slot SwiGLU FFN.
 
-The MoE expert FFN executes one (C x K) @ (K x N) per physical expert slot.
-On TPU we tile (M, N, K) so each block's working set sits in VMEM and the
-MXU sees 128-aligned contractions:
+The MoE expert FFN runs one SwiGLU per physical expert slot over that
+slot's capacity-padded (C, D) token buffer, of which only the first
+``count`` rows hold routed pairs.
 
-  grid = (G, M/bm, N/bn, K/bk)   -- K innermost for accumulation
-  x block  (1, bm, bk), w block (1, bk, bn), out block (1, bm, bn)
+The fp kernel (:func:`grouped_ffn_pallas`) runs only those rows.  Its grid
+is ``(work, F/bf)``: one work item per filled (slot, m-tile) pair, taken
+from scalar-prefetched tables, with the F tiles innermost.  Each F step
+feeds one x block to both up projections, gates in VMEM and accumulates
+the down projection into a (bm, D) fp32 VMEM accumulator, so a slot's
+weights are read once per filled m-tile and the (bm, F) activations never
+touch HBM.  The grid is sized by a static bound on the work; steps past the
+real work repeat the last block indices, so they fetch nothing, and skip
+the math.
+Output tiles no work item visits are left unwritten.
 
-The fp32 accumulator lives in a VMEM scratch buffer across the K steps
-(standard Pallas matmul pattern); the final K step casts to the output
-dtype.  Capacity-padded rows are zero on input, so no masking is needed
-inside the kernel (zeros contribute zeros).
+The w8a8 kernels tile every slot densely, ``grid = (G, M/bm, N/bn,
+K/bk)`` with K innermost, and accumulate in int32; capacity-padded rows are
+zero codes on input, so no masking is needed inside them.
 """
 
 from __future__ import annotations
@@ -22,60 +29,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["grouped_matmul_kernel", "grouped_matmul_pallas",
-           "grouped_swiglu_kernel", "grouped_swiglu_pallas",
+__all__ = ["grouped_ffn_kernel", "grouped_ffn_pallas",
            "grouped_matmul_q8_kernel", "grouped_matmul_q8_pallas",
            "grouped_swiglu_q8_kernel", "grouped_swiglu_q8_pallas"]
 
 
-def grouped_matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int):
-    @pl.when(pl.program_id(3) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+def grouped_ffn_kernel(slot_ref, tile_ref, nwork_ref, base_ref, x_ref,
+                       w1_ref, w3_ref, w2_ref, o_ref, acc_ref, *,
+                       f_steps: int):
+    """One F tile of one work item: ``acc += swiglu(x @ w1, x @ w3) @ w2``.
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[0], w_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(pl.program_id(3) == k_steps - 1)
-    def _store():
-        o_ref[0, ...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def grouped_swiglu_kernel(x_ref, w1_ref, w3_ref, o_ref, acc_h, acc_g, *,
-                          k_steps: int):
-    """Fused grouped SwiGLU: ``silu(x@w1) * (x@w3)`` in one invocation.
-
-    The unfused path runs two grouped GEMMs that each stream the same x block
-    out of HBM and round-trip their (G, M, N) intermediates before the
-    elementwise gate.  Here one x block feeds both MXU contractions, the two
-    fp32 accumulators live in VMEM across the K steps, and the silu gate is
-    applied on the final K step -- the h/g intermediates never touch HBM.
+    The scalar-prefetch tables (``slot_ref``, ``tile_ref``, ``nwork_ref``,
+    ``base_ref``) only steer the index maps here; a step past the real
+    work does nothing.
     """
-    @pl.when(pl.program_id(3) == 0)
-    def _init():
-        acc_h[...] = jnp.zeros_like(acc_h)
-        acc_g[...] = jnp.zeros_like(acc_g)
+    f = pl.program_id(1)
 
-    x_blk = x_ref[0]
-    acc_h[...] += jax.lax.dot_general(
-        x_blk, w1_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    acc_g[...] += jax.lax.dot_general(
-        x_blk, w3_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    @pl.when(pl.program_id(0) < nwork_ref[0])
+    def _work():
+        @pl.when(f == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(pl.program_id(3) == k_steps - 1)
-    def _store():
-        h = acc_h[...]
-        act = h * jax.lax.logistic(h) * acc_g[...]
-        o_ref[0, ...] = act.astype(o_ref.dtype)
+        x = x_ref[0]
+        h = jax.lax.dot_general(x, w1_ref[0], (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        g = jax.lax.dot_general(x, w3_ref[0], (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        act = (h * jax.lax.logistic(h) * g).astype(x.dtype)
+        acc_ref[...] += jax.lax.dot_general(
+            act, w2_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(f == f_steps - 1)
+        def _store():
+            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
 def grouped_matmul_q8_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
@@ -108,10 +96,9 @@ def grouped_swiglu_q8_kernel(x_ref, w1_ref, w3_ref, xs_ref, w1s_ref, w3s_ref,
                              o_ref, acc_h, acc_g, *, k_steps: int):
     """Fused w8a8 SwiGLU: two int32 accumulators, fp32 gate on the last step.
 
-    Same structure as :func:`grouped_swiglu_kernel` -- one int8 x block feeds
-    both MXU contractions -- but accumulation is integer-exact and the h/g
-    dequant happens in VMEM right before the silu gate, so the quantized
-    path keeps the no-HBM-round-trip property of the fp kernel.
+    One int8 x block feeds both MXU contractions, accumulation is
+    integer-exact, and the h/g dequant happens in VMEM right before the silu
+    gate, so the h/g intermediates never round-trip HBM.
     """
     @pl.when(pl.program_id(3) == 0)
     def _init():
@@ -138,59 +125,63 @@ def grouped_swiglu_q8_kernel(x_ref, w1_ref, w3_ref, xs_ref, w1s_ref, w3s_ref,
         o_ref[0, ...] = h * jax.lax.logistic(h) * g
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk"))
-def grouped_swiglu_pallas(x: jax.Array, w1: jax.Array, w3: jax.Array, *,
-                          bm: int = 128, bn: int = 128,
-                          bk: int = 128) -> jax.Array:
-    """x: (G, M, K), w1/w3: (G, K, N) -> silu(x@w1) * (x@w3): (G, M, N)."""
-    G, M, K = x.shape
-    _, _, N = w1.shape
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    if M % bm or N % bn or K % bk:
-        raise ValueError(f"dims ({M},{N},{K}) not divisible by blocks "
-                         f"({bm},{bn},{bk})")
-    k_steps = K // bk
-    grid = (G, M // bm, N // bn, k_steps)
-    return pl.pallas_call(
-        functools.partial(grouped_swiglu_kernel, k_steps=k_steps),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda g, i, j, k: (g, i, k)),
-            pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g, k, j)),
-            pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g, k, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
-        out_shape=jax.ShapeDtypeStruct((G, M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
-                        pltpu.VMEM((bm, bn), jnp.float32)],
-    )(x, w1, w3)
+@functools.partial(jax.jit, static_argnames=("bm", "bf"))
+def grouped_ffn_pallas(slot: jax.Array, tile: jax.Array, nwork: jax.Array,
+                       base: jax.Array, x: jax.Array, w1: jax.Array,
+                       w3: jax.Array, w2: jax.Array, *, bm: int,
+                       bf: int) -> jax.Array:
+    """SwiGLU FFN over the work items' row tiles.
 
+    ``slot``/``tile``: (W,) int32, the slot and m-tile of each work item,
+    every entry from ``nwork[0]`` on repeating the last real one; ``nwork``:
+    (1,) int32; ``base``: (1,) int32, the weight row of slot 0.  x: (G, M,
+    D); w1/w3: (G', D, F); w2: (G', F, D) with ``G' >= base + G``, ``M % bm
+    == 0`` and ``F % bf == 0``.  Returns (G, M, D) in x's dtype, written on
+    the work items' tiles only.
+    """
+    G, M, D = x.shape
+    F = w1.shape[2]
+    if M % bm or F % bf:
+        raise ValueError(f"dims (M={M}, F={F}) not divisible by blocks "
+                         f"({bm}, {bf})")
+    f_steps = F // bf
 
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk"))
-def grouped_matmul_pallas(x: jax.Array, w: jax.Array, *, bm: int = 128,
-                          bn: int = 128, bk: int = 128) -> jax.Array:
-    """x: (G, M, K) @ w: (G, K, N) -> (G, M, N)."""
-    G, M, K = x.shape
-    _, _, N = w.shape
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    if M % bm or N % bn or K % bk:
-        raise ValueError(f"dims ({M},{N},{K}) not divisible by blocks "
-                         f"({bm},{bn},{bk})")
-    k_steps = K // bk
-    grid = (G, M // bm, N // bn, k_steps)
+    def f_of(w, f, nwork):
+        # Past the real work: stay on the last F tile, as the last real
+        # step left it, so the weight blocks are not fetched again.
+        return jnp.where(w < nwork[0], f, f_steps - 1)
+
+    def x_map(w, f, slot, tile, nwork, base):
+        return slot[w], tile[w], 0
+
+    def up_map(w, f, slot, tile, nwork, base):
+        return base[0] + slot[w], 0, f_of(w, f, nwork)
+
+    def down_map(w, f, slot, tile, nwork, base):
+        return base[0] + slot[w], f_of(w, f, nwork), 0
+
+    isz = x.dtype.itemsize
+    # Double-buffered x, out, w1, w3, w2 blocks plus the fp32 accumulator.
+    vmem = 2 * isz * (2 * bm * D + 3 * D * bf) + 4 * bm * D
     return pl.pallas_call(
-        functools.partial(grouped_matmul_kernel, k_steps=k_steps),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda g, i, j, k: (g, i, k)),
-            pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g, k, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
-        out_shape=jax.ShapeDtypeStruct((G, M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-    )(x, w)
+        functools.partial(grouped_ffn_kernel, f_steps=f_steps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(slot.shape[0], f_steps),
+            in_specs=[
+                pl.BlockSpec((1, bm, D), x_map),
+                pl.BlockSpec((1, D, bf), up_map),
+                pl.BlockSpec((1, D, bf), up_map),
+                pl.BlockSpec((1, bf, D), down_map),
+            ],
+            out_specs=pl.BlockSpec((1, bm, D), x_map),
+            scratch_shapes=[pltpu.VMEM((bm, D), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((G, M, D), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(32 << 20, vmem + (8 << 20))),
+    )(slot, tile, nwork, base, x, w1, w3, w2)
 
 
 @functools.partial(jax.jit,

@@ -1,28 +1,30 @@
-"""Oracle for the grouped GEMM: per-group dense matmul."""
+"""Oracles for the grouped GEMM kernels: dense per-group products."""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["grouped_matmul_ref", "grouped_swiglu_ref",
-           "grouped_matmul_q8_ref", "grouped_swiglu_q8_ref"]
+__all__ = ["grouped_ffn_ref", "stack_layer", "grouped_matmul_q8_ref",
+           "grouped_swiglu_q8_ref"]
 
 
-def grouped_matmul_ref(x: jax.Array, w: jax.Array) -> jax.Array:
-    """x: (G, M, K); w: (G, K, N) -> (G, M, N), fp32 accumulation."""
-    out = jnp.einsum("gmk,gkn->gmn", x.astype(jnp.float32),
-                     w.astype(jnp.float32))
-    return out.astype(x.dtype)
+def stack_layer(ws, layer: jax.Array | None) -> tuple[jax.Array, ...]:
+    """Layer ``layer`` of each (L, G, ...) weight stack in ``ws``, or the
+    weights as they are when ``layer`` is None."""
+    if layer is None:
+        return tuple(ws)
+    return tuple(jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
+                 for w in ws)
 
 
-def grouped_swiglu_ref(x: jax.Array, w1: jax.Array, w3: jax.Array) -> jax.Array:
-    """silu(x@w1) * (x@w3) per group, fp32 accumulation and gating."""
-    h = jnp.einsum("gmk,gkn->gmn", x.astype(jnp.float32),
-                   w1.astype(jnp.float32))
-    g = jnp.einsum("gmk,gkn->gmn", x.astype(jnp.float32),
-                   w3.astype(jnp.float32))
-    return (jax.nn.silu(h) * g).astype(x.dtype)
+def grouped_ffn_ref(xs: jax.Array, w1: jax.Array, w3: jax.Array,
+                    w2: jax.Array) -> jax.Array:
+    """Dense per-slot SwiGLU over every row: xs (G, C, D), w1/w3 (G, D, F),
+    w2 (G, F, D) -> (G, C, D), each product in the operands' dtype."""
+    h = jnp.einsum("gcd,gdf->gcf", xs, w1)
+    g = jnp.einsum("gcd,gdf->gcf", xs, w3)
+    return jnp.einsum("gcf,gfd->gcd", jax.nn.silu(h) * g, w2)
 
 
 def grouped_matmul_q8_ref(q: jax.Array, row_scale: jax.Array, wq: jax.Array,

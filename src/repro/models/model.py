@@ -44,6 +44,9 @@ class MoECounters(NamedTuple):
     drops: jax.Array     # pairs dropped at pair or slot capacity
     absent: jax.Array    # pairs routed to experts held on other chips
                          #   (an expert share; a constant 0 otherwise)
+    rows_run: jax.Array  # slot rows whose products the expert FFN
+                         #   computed, over the layer's devices, the
+                         #   count-aware kernel's tile padding included
 
 
 def _stack_blocks(blocks: list[BlockParams]) -> BlockParams:
@@ -141,7 +144,7 @@ def forward(
         bias_seg = None
         if router_bias is not None:
             bias_seg = router_bias[jnp.array(seg.layer_ids)]
-        x, aux, drops, counts, _ = segment_apply(
+        x, aux, drops, counts, _, _ = segment_apply(
             x, seg, sp, cfg, rcfg, pctx, router_bias=bias_seg)
         aux_tot += aux
         drops_tot += drops.sum()
@@ -244,12 +247,12 @@ def _cached_step(params: LMParams, caches, tokens: jax.Array,
         x = embed(tokens, params.embedding)
     segs = segments_for(cfg, rcfg)
     share = cfg.moe is not None and cfg.moe.holds_share
-    new_caches, held, drops_all, absent = [], [], [], []
+    new_caches, held, drops_all, absent, rows_all = [], [], [], [], []
     for seg, sp, cache in zip(segs, params.segments, caches):
         bias_seg = None
         if router_bias is not None:
             bias_seg = router_bias[jnp.array(seg.layer_ids)]
-        x, _aux, drops, counts, nc = segment_apply(
+        x, _aux, drops, counts, rows, nc = segment_apply(
             x, seg, sp, cfg, rcfg, pctx, caches=cache,
             router_bias=bias_seg, decode=decode, valid_len=valid_len)
         new_caches.append(nc)
@@ -263,10 +266,12 @@ def _cached_step(params: LMParams, caches, tokens: jax.Array,
             routed = here
         held.append(routed - drops)
         drops_all.append(drops)
+        rows_all.append(rows)
     counters = MoECounters(
         held=jnp.concatenate(held), drops=jnp.concatenate(drops_all),
         absent=(jnp.concatenate(absent) if share
-                else jnp.zeros((cfg.num_layers,), jnp.int32)))
+                else jnp.zeros((cfg.num_layers,), jnp.int32)),
+        rows_run=jnp.concatenate(rows_all))
     return _head(params, x), tuple(new_caches), counters
 
 

@@ -261,10 +261,11 @@ def block_moe_config(cfg: ModelConfig, rcfg: RuntimeConfig,
 
 def moe_slot_rows(cfg: ModelConfig, rcfg: RuntimeConfig, pctx: ParallelCtx,
                   batch: int, seq: int, *, decode: bool = False) -> int:
-    """Slot rows the expert FFN of one MoE layer runs on a (batch, seq)
-    input, summed over the devices: each runs ``overlap_chunks`` x
+    """Capacity rows of one MoE layer's expert slots on a (batch, seq)
+    input, summed over the devices: each holds ``overlap_chunks`` x
     ``num_slots`` x ``cap_slot`` rows, whether a routed pair fills a row
-    or not."""
+    or not.  The dense FFN runs them all; the count-aware kernel only the
+    filled row tiles (``MoECounters.rows_run``)."""
     m = block_moe_config(cfg, rcfg, pctx, batch, seq, decode=decode)
     devices = 1 if pctx.mesh is None else (pctx.batch_size_divisor
                                            * pctx.ep_size)
@@ -406,14 +407,16 @@ def init_cache_block(cfg: ModelConfig, kind: str, batch: int, max_seq: int,
 
 def _ep_moe_block(x: jax.Array, mp: MoEParams, mcfg: MoEConfig,
                   pctx: ParallelCtx, router_bias: jax.Array | None):
-    """shard_map island: (B, S, D) -> (B, S, D), per-device aux/stats."""
+    """shard_map island: (B, S, D) -> (B, S, D), aux, drops, counts and
+    the expert FFN's rows run, summed over devices."""
     B, S, D = x.shape
     if pctx.mesh is None:
         y, aux, stats = moe_layer_local(
             x.reshape(-1, D), mp, mcfg, axis_name=None,
             router_bias=router_bias)
         return (y.reshape(B, S, D), aux,
-                stats.drops_dispatch + stats.drops_slot, stats.counts)
+                stats.drops_dispatch + stats.drops_slot, stats.counts,
+                stats.rows_run)
 
     from jax.sharding import PartitionSpec as P
 
@@ -439,7 +442,8 @@ def _ep_moe_block(x: jax.Array, mp: MoEParams, mcfg: MoEConfig,
             counts = jax.lax.psum(stats.counts, ba)  # identical across model
         else:
             counts = jax.lax.psum(stats.counts, all_axes)
-        return y.reshape(Bl, Sl, D), aux[None], drops, counts
+        return (y.reshape(Bl, Sl, D), aux[None], drops, counts,
+                stats.rows_run[None])
 
     has_shared = mp.shared_w1 is not None
     sw_spec = P(None, None) if has_shared else P()
@@ -449,12 +453,12 @@ def _ep_moe_block(x: jax.Array, mp: MoEParams, mcfg: MoEConfig,
         in_specs=(x_spec, P(None, None), P(ma, None, None),
                   P(ma, None, None), P(ma, None, None), sw_spec, sw_spec,
                   sw_spec, bias_spec),
-        out_specs=(x_spec, P(all_axes), P(all_axes), P(None)),
+        out_specs=(x_spec, P(all_axes), P(all_axes), P(None), P(all_axes)),
     )
-    y, aux, drops, counts = fn(x, mp.router, mp.w1, mp.w3, mp.w2,
-                               mp.shared_w1, mp.shared_w3, mp.shared_w2,
-                               router_bias)
-    return y, aux.sum(), drops.sum(), counts
+    y, aux, drops, counts, rows = fn(x, mp.router, mp.w1, mp.w3, mp.w2,
+                                     mp.shared_w1, mp.shared_w3, mp.shared_w2,
+                                     router_bias)
+    return y, aux.sum(), drops.sum(), counts, rows.sum()
 
 
 def _attention(h: jax.Array, bp: BlockParams, cfg: ModelConfig,
@@ -504,7 +508,9 @@ def block_apply(
     decode: bool = False,
     valid_len=None,
 ):
-    """One residual block.  Returns (x, aux, drops, counts, new_cache).
+    """One residual block.  Returns (x, aux, drops, counts, rows_run,
+    new_cache): the MoE layer's dropped pairs, per-expert load and expert
+    FFN rows run (zeros without experts).
 
     Modes: train/full forward (cache None), chunked prefill (cache given,
     decode False -- writes the cache at offset cache.length), decode
@@ -516,6 +522,7 @@ def block_apply(
     aux = jnp.zeros((), jnp.float32)
     drops = jnp.zeros((), jnp.int32)
     counts = jnp.zeros((cfg.moe.num_experts if cfg.moe else 1,), jnp.int32)
+    rows_run = jnp.zeros((), jnp.int32)
     new_cache = cache
 
     x = wsc(x, pctx, "seq", decode=decode)
@@ -546,8 +553,8 @@ def block_apply(
         if ffn_kind == "moe":
             B, S, _ = x.shape
             mcfg = block_moe_config(cfg, rcfg, pctx, B, S, decode=decode)
-            y2, aux, drops, counts = _ep_moe_block(h2, bp.moe, mcfg, pctx,
-                                                   router_bias)
+            y2, aux, drops, counts, rows_run = _ep_moe_block(
+                h2, bp.moe, mcfg, pctx, router_bias)
         else:
             # Dense FFN: gather S, hidden shards over model, scatter back.
             h2 = wsc(h2, pctx, "full", decode=decode)
@@ -555,7 +562,7 @@ def block_apply(
                 y2 = dense_swiglu(h2, *bp.ffn)
             y2 = wsc(y2, pctx, "seq", decode=decode)
         x = x + y2
-    return x, aux, drops, counts, new_cache
+    return x, aux, drops, counts, rows_run, new_cache
 
 
 def segment_apply(
@@ -573,8 +580,9 @@ def segment_apply(
 ):
     """Run one homogeneous segment (scan if stacked, loop otherwise).
 
-    Returns (x, aux_sum, drops (L_seg,), counts (L_seg, E), new_caches):
-    the routed pairs each layer dropped and its per-expert load.
+    Returns (x, aux_sum, drops (L_seg,), counts (L_seg, E), rows_run
+    (L_seg,), new_caches): the routed pairs each layer dropped, its
+    per-expert load and the rows its expert FFN ran.
     """
     aux_tot = jnp.zeros((), jnp.float32)
 
@@ -589,6 +597,7 @@ def segment_apply(
             aux_c = jnp.zeros((), jnp.float32)
             drops_c = []
             counts_c = []
+            rows_c = []
             nc_list = []
             for j, kind_j in enumerate(seg.cycle):
 
@@ -603,14 +612,16 @@ def segment_apply(
                            else layer_in["cache"][j])
                 bias_j = (None if layer_in.get("bias") is None
                           else layer_in["bias"][j])
-                x, aux, drops, counts, ncj = run(x, layer_in["p"][j],
-                                                 cache_j, bias_j)
+                x, aux, drops, counts, rows, ncj = run(
+                    x, layer_in["p"][j], cache_j, bias_j)
                 aux_c += aux
                 drops_c.append(drops)
                 counts_c.append(counts)
+                rows_c.append(rows)
                 nc_list.append(ncj)
             outs = {"aux": aux_c, "drops": jnp.stack(drops_c),
-                    "counts": jnp.stack(counts_c)}
+                    "counts": jnp.stack(counts_c),
+                    "rows": jnp.stack(rows_c)}
             if caches is not None:
                 outs["cache"] = tuple(nc_list)
             return x, outs
@@ -623,7 +634,7 @@ def segment_apply(
         x, outs = jax.lax.scan(body, x, ins)
         counts = outs["counts"].reshape(seg.length, -1)
         return (x, outs["aux"].sum(), outs["drops"].reshape(seg.length),
-                counts, outs.get("cache"))
+                counts, outs["rows"].reshape(seg.length), outs.get("cache"))
 
     stacked = isinstance(params, BlockParams)  # stacked leaves (L, ...)
     if stacked and rcfg.scan_layers and seg.length >= rcfg.min_scan_len:
@@ -637,22 +648,37 @@ def segment_apply(
             run_block = jax.checkpoint(run_block, prevent_cse=False)
 
         def body(carry, layer_in):
-            xo, aux, drops, counts, nc = run_block(
-                carry, layer_in["p"], layer_in.get("cache"),
-                layer_in.get("bias"))
-            out = {"aux": aux, "drops": drops, "counts": counts}
+            pp = layer_in["p"]
+            if "layer" in layer_in:
+                pp = pp._replace(moe=pp.moe._replace(
+                    w1=params.moe.w1, w3=params.moe.w3, w2=params.moe.w2,
+                    layer=layer_in["layer"]))
+            xo, aux, drops, counts, rows, nc = run_block(
+                carry, pp, layer_in.get("cache"), layer_in.get("bias"))
+            out = {"aux": aux, "drops": drops, "counts": counts,
+                   "rows": rows}
             if layer_in.get("cache") is not None:
                 out["cache"] = nc
             return xo, out
 
         ins = {"p": params}
+        moe = params.moe
+        if moe is not None and pctx.mesh is None:
+            # The expert stacks stay whole and each layer reads its experts
+            # at its index: a scanned slice of them would be copied out to
+            # feed the count-aware FFN kernel.  On a mesh the layer's
+            # shard_map takes the sliced shard of its experts.
+            ins["p"] = params._replace(moe=moe._replace(w1=None, w3=None,
+                                                        w2=None))
+            ins["layer"] = jnp.arange(seg.length, dtype=jnp.int32)
         if caches is not None:
             ins["cache"] = caches
         if router_bias is not None:
             ins["bias"] = router_bias
         x, outs = jax.lax.scan(body, x, ins)
         aux_tot += outs["aux"].sum()
-        return x, aux_tot, outs["drops"], outs["counts"], outs.get("cache")
+        return (x, aux_tot, outs["drops"], outs["counts"], outs["rows"],
+                outs.get("cache"))
 
     # Unstacked / short segment: python loop.
     if stacked:
@@ -663,6 +689,7 @@ def segment_apply(
     new_caches = []
     drops_l = []
     counts_l = []
+    rows_l = []
     for i, bp in enumerate(plist):
         cache_l = None
         if caches is not None:
@@ -677,16 +704,18 @@ def segment_apply(
 
         if rcfg.remat and not decode and caches is None:
             run_block = jax.checkpoint(run_block, prevent_cse=False)
-        x, aux, drops, counts, nc = run_block(x, bp, cache_l, bias_l)
+        x, aux, drops, counts, rows, nc = run_block(x, bp, cache_l, bias_l)
         aux_tot += aux
         drops_l.append(drops)
         counts_l.append(counts)
+        rows_l.append(rows)
         new_caches.append(nc)
     drops_seg = jnp.stack(drops_l) if drops_l else jnp.zeros((0,), jnp.int32)
     counts_seg = jnp.stack(counts_l) if counts_l else jnp.zeros(
         (0, 1), jnp.int32)
+    rows_seg = jnp.stack(rows_l) if rows_l else jnp.zeros((0,), jnp.int32)
     if caches is None:
         new_caches = None
     elif not isinstance(caches, (list, tuple)):
         new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)
-    return x, aux_tot, drops_seg, counts_seg, new_caches
+    return x, aux_tot, drops_seg, counts_seg, rows_seg, new_caches

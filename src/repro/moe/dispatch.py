@@ -243,10 +243,10 @@ def moe_layer_reference(x: jax.Array, params, cfg, *,
     n_main = dist.w1.shape[0]
     out = jnp.concatenate([
         grouped_ffn(xs[:n_main], valid[:n_main], dist.w1, dist.w3, dist.w2,
-                    use_kernel=cfg.use_kernel, ffn_dtype=cfg.ffn_dtype),
+                    use_kernel=cfg.use_kernel, ffn_dtype=cfg.ffn_dtype)[0],
         grouped_ffn(xs[n_main:], valid[n_main:], dist.w1r, dist.w3r,
                     dist.w2r, use_kernel=cfg.use_kernel,
-                    ffn_dtype=cfg.ffn_dtype),
+                    ffn_dtype=cfg.ffn_dtype)[0],
     ], axis=0)
 
     if cfg.dispatch_mode == "replicated":

@@ -55,7 +55,9 @@ class MoEConfig:
     # under chunk i's grouped FFN (repro.moe.stages; DESIGN.md S11).
     # Bit-identical to unchunked at zero-drop capacities; must divide the
     # local token count at call time.
-    use_kernel: bool = False       # Pallas grouped-GEMM for expert FFN
+    use_kernel: bool = False       # expert FFN's Pallas kernels off the
+    # TPU too (interpret mode), and the w8a8 path's; the TPU runs the fp
+    # kernel regardless
     dispatch_mode: str = "a2a"     # "a2a" | "replicated" | "hier_a2a"
     # "replicated": tokens are replicated across the EP axis (decode path /
     # exact reference); each rank computes the quota-assigned share of items
@@ -122,6 +124,11 @@ class MoEParams(NamedTuple):
     shared_w1: jax.Array | None = None   # (D, F_sh)
     shared_w3: jax.Array | None = None
     shared_w2: jax.Array | None = None   # (F_sh, D)
+    layer: jax.Array | None = None  # () int32: w1/w3/w2 are a scanned
+                             #   segment's whole (L, E_local, ...) stacks and
+                             #   this layer's experts sit at this index
+                             #   (read in place, never sliced out); None
+                             #   when they are one layer's
 
 
 def default_capacities(tokens_per_rank: int, top_k: int, ep_size: int,

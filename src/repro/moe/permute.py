@@ -359,6 +359,18 @@ def fused_unbucket(out: SlotOut, meta: BucketMeta) -> jax.Array:
     return jnp.where(meta.valid[:, :, None], ret, jnp.zeros((), ret.dtype))
 
 
+def _kept_rows(rows: jax.Array, kept: jax.Array,
+               weights: jax.Array) -> jax.Array:
+    """(N, D) gathered rows times their items' gate weights, zero where an
+    item was not kept.
+
+    A select, not a multiply by a zero weight: a dropped item's gather
+    reads a placeholder row, which the count-aware FFN may have left
+    unwritten, and ``NaN * 0`` is NaN."""
+    w = weights.reshape(-1)[:, None].astype(rows.dtype)
+    return jnp.where(kept[:, None], rows * w, jnp.zeros((), rows.dtype))
+
+
 def _tokenwise_sum(vals: jax.Array) -> jax.Array:
     """(T, k, D) -> (T, D) as a strict left fold over k.
 
@@ -386,10 +398,10 @@ def fused_combine(
     """
     T, k = weights.shape
     D = ret_x.shape[-1]
-    safe_dst = jnp.where(disp.item_kept, disp.item_dst, 0)
-    safe_pos = jnp.where(disp.item_kept, disp.item_pos, 0)
-    flat_w = weights.reshape(-1) * disp.item_kept.astype(weights.dtype)
-    vals = ret_x[safe_dst, safe_pos] * flat_w[:, None].astype(ret_x.dtype)
+    kept = disp.item_kept
+    safe_dst = jnp.where(kept, disp.item_dst, 0)
+    safe_pos = jnp.where(kept, disp.item_pos, 0)
+    vals = _kept_rows(ret_x[safe_dst, safe_pos], kept, weights)
     return _tokenwise_sum(vals.reshape(T, k, D))
 
 
@@ -467,9 +479,9 @@ def fused_replicated_combine(
 ) -> jax.Array:
     """Per-item gather from the slot buffers + token-major weighted sum."""
     T, k = weights.shape
-    safe_slot = jnp.where(bucket.item_ok, bucket.item_slot, 0)
-    safe_pos = jnp.where(bucket.item_ok, bucket.item_pos, 0)
-    flat_w = weights.reshape(-1) * bucket.item_ok.astype(weights.dtype)
+    ok = bucket.item_ok
+    safe_slot = jnp.where(ok, bucket.item_slot, 0)
+    safe_pos = jnp.where(ok, bucket.item_pos, 0)
     rows = _slot_rows(out, safe_slot, safe_pos)
-    vals = rows * flat_w[:, None].astype(rows.dtype)
+    vals = _kept_rows(rows, ok, weights)
     return _tokenwise_sum(vals.reshape(T, k, rows.shape[-1]))

@@ -51,6 +51,7 @@ from repro.core.quantize import (
     payload_bytes_per_item,
     split_wire_int8,
 )
+from repro.kernels.grouped_gemm.ref import stack_layer
 from repro.moe.distribute import materialize_replica_stack
 from repro.moe.expert import grouped_ffn
 from repro.moe.gating import GateOut, gate, rack_copy_volumes
@@ -120,6 +121,9 @@ class MoEStats(NamedTuple):
     fallback_plans: jax.Array | None = None          # () int32
     dropped_payload_tokens: jax.Array | None = None  # () int32
     quarantined_ranks: jax.Array | None = None       # () int32
+    # Rows whose products the expert FFN computed, over every slot and
+    # overlap chunk, the count-aware kernel's tile padding included.
+    rows_run: jax.Array | None = None                # () int32
 
 
 class StageCtx(NamedTuple):
@@ -165,6 +169,8 @@ class DistributeState(NamedTuple):
     w1r: jax.Array       # (N_slot, D, F) streamed replicas
     w3r: jax.Array       # (N_slot, D, F)
     w2r: jax.Array       # (N_slot, F, D)
+    layer: jax.Array | None = None  # the mains' layer index when they are
+                         #    a segment's whole stacks (MoEParams.layer)
 
 
 class DispatchState(NamedTuple):
@@ -188,6 +194,8 @@ class DispatchState(NamedTuple):
                          #    wire scales when wire_dtype == ffn_dtype ==
                          #    "int8": the slot buffers stay encoded and feed
                          #    the w8a8 kernel directly (no dequant round-trip)
+    max_rows: int | None = None  # static: the items bucketed, which bound
+                         #    the valid rows of all slots together
 
 
 # --------------------------------------------------------------------------
@@ -528,11 +536,12 @@ def distribute_stage(ctx: StageCtx, params, gs: GateState,
     """
     cfg = ctx.cfg
     w1r, w3r, w2r = materialize_replica_stack(
-        (params.w1, params.w3, params.w2), ps.plan.x, gs.my, ctx.axis_name,
+        stack_layer((params.w1, params.w3, params.w2), params.layer),
+        ps.plan.x, gs.my, ctx.axis_name,
         n_chunks=cfg.distribute_chunks, racks=cfg.racks,
         wire_dtype=cfg.wire_dtype)
     return DistributeState(w1=params.w1, w3=params.w3, w2=params.w2,
-                           w1r=w1r, w3r=w3r, w2r=w2r)
+                           w1r=w1r, w3r=w3r, w2r=w2r, layer=params.layer)
 
 
 def _distribute_with_ladder(
@@ -598,7 +607,8 @@ def dispatch_stage(ctx: StageCtx, x_chunk: jax.Array,
             occ_offset=occ_offset, routed=routed,
         )
         return DispatchState(xs=rb.xs, valid=rb.valid, inverse=rb,
-                             drops_dispatch=zero, drops_slot=rb.drops)
+                             drops_dispatch=zero, drops_slot=rb.drops,
+                             max_rows=expert_ids.size)
 
     # Single-sort permutation engine (repro.moe.permute): on a factored
     # mesh the same destination-major buffers ride the two-hop tiered
@@ -626,30 +636,34 @@ def dispatch_stage(ctx: StageCtx, x_chunk: jax.Array,
         xs = decode_wire(xs, cfg.wire_dtype, x_chunk.dtype)
     return DispatchState(xs=xs, valid=valid, inverse=(disp, meta),
                          drops_dispatch=disp.drops, drops_slot=slot_drops,
-                         xs_scale=xs_scale)
+                         xs_scale=xs_scale,
+                         max_rows=recv_x.shape[0] * recv_x.shape[1])
 
 
-def compute_stage(ctx: StageCtx, ds: DispatchState,
-                  dist: DistributeState) -> tuple[jax.Array, ...]:
+def compute_stage(ctx: StageCtx, ds: DispatchState, dist: DistributeState
+                  ) -> tuple[tuple[jax.Array, ...], jax.Array]:
     """Grouped FFN over this rank's physical slots for one chunk.
 
     The main slots run against the mains and the replica slots against the
     streamed replicas: the same per-slot SwiGLU, with no (num_slots, D, F)
     weight stack built to feed it.  Returns the slot outputs as the two
     consecutive slot ranges ``(mains, replicas)``, each
-    ``(slots, cap_slot, D)``; CombineStage reads them without concatenating.
+    ``(slots, cap_slot, D)``, which CombineStage reads without
+    concatenating, and the rows the FFN ran (int32).
     """
     cfg = ctx.cfg
-    n_main = dist.w1.shape[0]
+    n_main = dist.w1.shape[-3]
 
-    def ffn(lo, hi, w1, w3, w2):
+    def ffn(lo, hi, w1, w3, w2, layer=None):
         return grouped_ffn(
             ds.xs[lo:hi], ds.valid[lo:hi], w1, w3, w2,
             use_kernel=cfg.use_kernel, ffn_dtype=cfg.ffn_dtype,
-            xs_scale=None if ds.xs_scale is None else ds.xs_scale[lo:hi])
+            xs_scale=None if ds.xs_scale is None else ds.xs_scale[lo:hi],
+            max_rows=ds.max_rows, layer=layer)
 
-    return (ffn(0, n_main, dist.w1, dist.w3, dist.w2),
-            ffn(n_main, None, dist.w1r, dist.w3r, dist.w2r))
+    out_m, rows_m = ffn(0, n_main, dist.w1, dist.w3, dist.w2, dist.layer)
+    out_r, rows_r = ffn(n_main, None, dist.w1r, dist.w3r, dist.w2r)
+    return (out_m, out_r), rows_m + rows_r
 
 
 def combine_stage(ctx: StageCtx, ds: DispatchState,
@@ -794,6 +808,7 @@ def run_staged_moe(
     drops_dispatch = jnp.zeros((), _I32)
     drops_slot = jnp.zeros((), _I32)
     max_slot_load = jnp.zeros((), _I32)
+    rows_run = jnp.zeros((), _I32)
     dropped_payload = jnp.zeros((), _I32)
     d_next = disp(0)
     for i in range(C):
@@ -806,7 +821,8 @@ def run_staged_moe(
             d_cur = d_cur._replace(xs=xs, valid=valid)
             dropped_payload = dropped_payload + n_bad
         with jax.named_scope("moe.ffn"):
-            outs = compute_stage(ctx, d_cur, dist)
+            outs, rows = compute_stage(ctx, d_cur, dist)
+        rows_run = rows_run + rows
         s, ln = bounds[i]
         with jax.named_scope("moe.combine"):
             y_chunk = combine_stage(ctx, d_cur, outs,
@@ -864,5 +880,6 @@ def run_staged_moe(
         fallback_plans=fallbacks,
         dropped_payload_tokens=(dropped_payload if res is not None else None),
         quarantined_ranks=quarantined,
+        rows_run=rows_run,
     )
     return y.astype(x.dtype), gs.gate_out.aux_loss, stats

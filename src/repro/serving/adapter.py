@@ -40,7 +40,8 @@ def make_engine_fns(params: LMParams, cfg: ModelConfig, rcfg: RuntimeConfig,
 
     @functools.cache
     def slot_rows(batch, seq, decode):
-        """Slot rows each layer's expert FFN runs per call (0: no experts)."""
+        """Capacity rows of each layer's expert slots per call (0: no
+        experts)."""
         return tuple(moe_slot_rows(cfg, rcfg, pctx, batch, seq, decode=decode)
                      if moe else 0 for moe in moe_layers)
 

@@ -10,8 +10,8 @@ and writes them out when the trace ends.  Off, :func:`span` hands back
 one shared no-op context: it reads no clock and allocates nothing.
 
 :func:`count` keeps the MoE counters a jitted step returns
-(``models.model.MoECounters``), with the slot rows the step's expert FFN
-ran and the top-k pairs of its valid tokens, while tracing is on.  The arrays
+(``models.model.MoECounters``), with the capacity rows of the step's
+expert slots and the top-k pairs of its valid tokens, while tracing is on.  The arrays
 stay on the device until a reader takes them once, after the traced
 window, with :func:`counts`, and then calls :func:`reset`.
 """
@@ -48,7 +48,7 @@ class StepCounts(NamedTuple):
 
     kind: str                    # "prefill" or "decode"
     counters: Any                # models.model.MoECounters (device arrays)
-    slot_rows: tuple[int, ...]   # slot rows the layer's expert FFN ran
+    slot_rows: tuple[int, ...]   # capacity rows of the layer's expert slots
     valid_pairs: tuple[int, ...]  # top-k pairs of the input's valid tokens
 
 

@@ -166,6 +166,45 @@ def test_fused_engine_multirank_bitwise(mode):
         assert np.array_equal(np.array(y_ref[r]), np.array(y_fus[r]))
 
 
+@pytest.mark.parametrize("cap_slot", [None, 4])
+@pytest.mark.parametrize("mode", ["a2a", "replicated"])
+def test_combine_never_reads_rows_past_the_counts(mode, cap_slot):
+    """The count-aware FFN leaves the rows past each slot's count
+    unwritten: poisoned with NaN there, the slot outputs combine to the
+    same tokens.  Expert 0 is never routed, so slot 0 is empty and every
+    dropped item's placeholder gather (slot 0, row 0) reads poison."""
+    from repro.moe import stages
+
+    cfg = _cfg("ultraep", dispatch_mode=mode, cap_slot=cap_slot)
+    params, x = _layer(cfg)
+    params = params._replace(router=params.router.at[:, 0].set(-10.0))
+    x = jnp.abs(x)
+    ctx = stages.make_stage_ctx(cfg, None)
+
+    @jax.jit
+    def front(params, x):
+        gs = stages.gate_stage(ctx, x, params.router)
+        ps = stages.plan_stage(ctx, gs)
+        dist = stages.distribute_stage(ctx, params, gs, ps)
+        ds = stages.dispatch_stage(ctx, x, gs.gate_out.expert_ids, gs, ps)
+        outs, _ = stages.compute_stage(ctx, ds, dist)
+        return ds._replace(max_rows=None), outs, gs.gate_out.weights
+
+    # One program combines both, so only the rows it reads can differ.
+    combine = jax.jit(lambda ds, outs, w: stages.combine_stage(ctx, ds,
+                                                               outs, w))
+    ds, outs, w = front(params, x)
+    assert not ds.valid[0].any()
+    assert (int(ds.drops_slot) > 0) == (cap_slot is not None)
+    n_main = outs[0].shape[0]
+    masks = (ds.valid[:n_main], ds.valid[n_main:])
+    poisoned = tuple(jnp.where(v[..., None], o, jnp.nan)
+                     for o, v in zip(outs, masks))
+    y = np.asarray(combine(ds, poisoned, w))
+    assert np.isfinite(y).all()
+    assert np.array_equal(y, np.asarray(combine(ds, outs, w)))
+
+
 # ------------------------------------------------- drop accounting ------
 
 def test_fused_drop_accounting_tight_caps():

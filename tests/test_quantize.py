@@ -222,11 +222,12 @@ def test_grouped_ffn_int8_close_to_fp32(rng):
     G, S, D, F = 4, 16, 32, 48
     xs = jnp.asarray(rng.normal(size=(G, S, D)), jnp.float32)
     valid = jnp.asarray(rng.random(size=(G, S)) < 0.8)
+    xs = jnp.where(valid[..., None], xs, 0)   # dispatch zeroes the rest
     w1 = jnp.asarray(rng.normal(size=(G, D, F)) * D ** -0.5, jnp.float32)
     w3 = jnp.asarray(rng.normal(size=(G, D, F)) * D ** -0.5, jnp.float32)
     w2 = jnp.asarray(rng.normal(size=(G, F, D)) * F ** -0.5, jnp.float32)
-    base = grouped_ffn(xs, valid, w1, w3, w2)
-    q8 = grouped_ffn(xs, valid, w1, w3, w2, ffn_dtype="int8")
+    base, _ = grouped_ffn(xs, valid, w1, w3, w2)
+    q8, _ = grouped_ffn(xs, valid, w1, w3, w2, ffn_dtype="int8")
     # Invalid rows stay exactly zero either way.
     assert not np.asarray(q8)[~np.asarray(valid)].any()
     scale = np.abs(np.asarray(base)).max()
